@@ -1133,6 +1133,8 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=1 / 256,
                     help="Table I graph scale factor")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     jobs = {"table1": lambda: table1(args.scale),
             "fig5": lambda: fig5(args.scale),
             "fig6": lambda: fig6(args.scale),

@@ -11,7 +11,7 @@
 * ``AbstractInterpreter`` is a tiny fixed-point abstract interpreter
   over jaxpr graphs: passes subclass it with a value lattice (``top`` /
   ``join`` / ``from_literal``) and per-primitive transfer rules, and it
-  handles the structural recursion — ``pjit`` call bodies, ``scan`` /
+  handles the structural recursion — ``jit`` call bodies, ``scan`` /
   ``while`` loop bodies (iterated to a join fixed point, widening to
   TOP on non-convergence so loop-carried values never produce phantom
   findings), ``cond`` branches, and custom-derivative call wrappers.
@@ -30,7 +30,8 @@ from pathlib import Path
 from typing import Any, Optional
 
 import jax
-from jax import core as jax_core
+from jax.core import DropVar
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 REPO_SRC_MARKER = "repro"
 _LOOP_FIXPOINT_ITERS = 4
@@ -100,13 +101,13 @@ def subjaxpr_params(eqn) -> list:
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else [val]
         for v in vals:
-            if isinstance(v, (jax_core.ClosedJaxpr, jax_core.Jaxpr)):
+            if isinstance(v, (ClosedJaxpr, Jaxpr)):
                 out.append(v)
     return out
 
 
 def _as_closed(j):
-    if isinstance(j, jax_core.ClosedJaxpr):
+    if isinstance(j, ClosedJaxpr):
         return j.jaxpr, list(j.consts)
     return j, []
 
@@ -157,7 +158,7 @@ class AbstractInterpreter:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _read(self, env, atom):
-        if isinstance(atom, jax_core.Literal):
+        if isinstance(atom, Literal):
             return self.from_literal(atom.val, atom.aval)
         return env.get(atom, self.top())
 
@@ -167,16 +168,15 @@ class AbstractInterpreter:
             out_vals = self._dispatch(eqn, in_vals)
             self.visit(eqn, in_vals, out_vals)
             for var, val in zip(eqn.outvars, out_vals):
-                if not isinstance(var, jax_core.DropVar):
+                if not isinstance(var, DropVar):
                     env[var] = val
 
     # -- structural primitives ---------------------------------------------
 
     def _dispatch(self, eqn, in_vals) -> list:
         prim = eqn.primitive.name
-        if prim in ("pjit", "closed_call", "core_call", "xla_call",
-                    "remat", "checkpoint", "custom_jvp_call",
-                    "custom_vjp_call", "custom_vjp_call_jaxpr"):
+        if prim in ("jit", "closed_call", "remat2", "custom_jvp_call",
+                    "custom_vjp_call"):
             subs = subjaxpr_params(eqn)
             if subs:
                 body = subs[0]

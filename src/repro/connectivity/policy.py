@@ -15,10 +15,8 @@ the registry's insert path. Two layers:
    * a pending DELETE batch that is small relative to the alive edge
      set (delete rate <= ``DELETE_RATE_SCOPED``) is a
      ``tombstone-delete`` (scoped recompute over the affected
-     components only, DESIGN.md §9; the ``-fused`` variant when the
-     autotune cache crowned ``pallas_fused`` for the surviving-graph
-     bucket) — a bulk drop falls through to a static rebuild over the
-     survivors;
+     components only, DESIGN.md §9) — a bulk drop falls through to a
+     static rebuild over the survivors;
    * density < ``MIN_SEGMENT_DENSITY``: s = 2|E|/|V| rounds to <= 1
      segment, so segmentation degenerates — run ``atomic_hook``
      (one segment, no scan overhead);
@@ -52,16 +50,17 @@ import numpy as np
 
 from repro.obs import trace as obs
 
-# the static engines the heuristic chooses between; the fused Pallas
-# backend and the k-out sampling engine join the measured (autotune)
-# candidate set below
+# the static engines the heuristic chooses between; the k-out sampling
+# engine joins the measured (autotune) candidate set below. No Pallas
+# backend is a candidate: none of the CC kernels lowers for a TPU, so
+# routing never reaches them (a forced method still fails loudly there)
 STATIC_METHODS = ("adaptive", "atomic_hook", "labelprop")
-AUTOTUNE_METHODS = STATIC_METHODS + ("pallas_fused", "sampled")
+AUTOTUNE_METHODS = STATIC_METHODS + ("sampled",)
 INCREMENTAL_ABSORB = "incremental-absorb"
 # delete-path routes (DESIGN.md §9): tombstone + scoped recompute over
-# the affected components only — the fused variant runs the scoped scan
-# through the cc_fused kernel (one launch); a bulk delete falls through
-# to a static rebuild over the surviving log instead
+# the affected components only — the fused variant (forced only, via
+# ``delete_route``) runs the scoped scan through the cc_fused kernel; a
+# bulk delete falls through to a static rebuild over the surviving log
 DYNAMIC_DELETE = "tombstone-delete"
 DYNAMIC_DELETE_FUSED = "tombstone-delete-fused"
 # the tree-aware route (DESIGN.md §14): classify the batch against the
@@ -268,16 +267,17 @@ class AutotuneCache:
             payload = json.load(fh)
         if payload.get("version") != CACHE_FORMAT_VERSION:
             return                      # stale format: start fresh
-        self.entries = dict(payload.get("entries", {}))
+        # a winner ``measure`` can no longer record (a backend since
+        # taken off the candidate set) is stale: drop it, never route it
+        self.entries = {k: v for k, v in payload.get("entries", {}).items()
+                        if v.get("method") in AUTOTUNE_METHODS}
 
     def measure(self, edges, num_nodes: int,
                 methods: tuple[str, ...] | None = None,
                 reps: int = 2) -> str:
-        """Time each candidate (static engines; plus the fused Pallas
-        backend when a real kernel backend is available — wall-clocking
-        the Python-interpreted emulation off-TPU is slow and says
-        nothing about the compiled kernel) on this graph, record and
-        return the wall-clock winner for its shape bucket. Every rep
+        """Time each candidate (``AUTOTUNE_METHODS`` — the same set on
+        every platform) on this graph, record and return the
+        wall-clock winner for its shape bucket. Every rep
         drains in-flight async work with ``block_until_ready`` BEFORE
         starting its timer — otherwise asynchronously dispatched work
         from the previous candidate flatters whichever method is
@@ -287,9 +287,7 @@ class AutotuneCache:
         included."""
         from repro.api import solve
         if methods is None:
-            from repro.kernels import default_interpret
-            methods = STATIC_METHODS if default_interpret() \
-                else AUTOTUNE_METHODS
+            methods = AUTOTUNE_METHODS
         edges = np.asarray(edges, np.int32).reshape(-1, 2)
         best_method, best_ms = None, float("inf")
         for method in methods:
@@ -371,12 +369,9 @@ def select_method(num_nodes: int, num_edges: int, *,
     only and get a method from ``STATIC_METHODS``; the registry's
     insert path also passes ``delta_edges`` and may get
     ``"incremental-absorb"`` back; its delete path passes
-    ``delta_deletes`` and may get a ``DELETE_METHODS`` route back — the
-    fused variant when the autotune cache's measured winner for the
-    surviving-graph bucket is ``pallas_fused`` (measured truth decides
-    which kernel backend runs the scoped scan, same as it decides the
-    static engine). Autotuned winners override the heuristic for the
-    static choice.
+    ``delta_deletes`` and may get ``tombstone-delete`` or
+    ``tombstone-delete-forest`` back. Autotuned winners override the
+    heuristic for the static choice.
     """
     if delta_edges is None and delta_deletes is None:
         # static call: one shared path with the facade's plan(), so
@@ -386,18 +381,11 @@ def select_method(num_nodes: int, num_edges: int, *,
                                        cache=cache)[0]
     f = extract_features(num_nodes, num_edges, delta_edges, delta_deletes)
     choice = heuristic_method(f)
-    if choice == INCREMENTAL_ABSORB:
-        return choice
-    if choice == DYNAMIC_DELETE_FOREST:
-        # the tree-aware route has no fused variant: its hot path is
-        # the short-circuit (no scan at all), and the scoped phases run
-        # over packed skeleton/crossing sets the fused kernel's
-        # segment-boundary prefetch does not model
+    if choice in (INCREMENTAL_ABSORB, DYNAMIC_DELETE,
+                  DYNAMIC_DELETE_FOREST):
+        # structural routes: the autotune cache ranks static engines
         return choice
     cache = default_cache() if cache is None else cache
-    if choice == DYNAMIC_DELETE:
-        hit = cache.lookup(f.num_nodes, max(f.remaining_edges, 1))
-        return DYNAMIC_DELETE_FUSED if hit == "pallas_fused" else choice
     lookup_edges = f.total_edges if f.delta_deletes is None \
         else max(f.remaining_edges, 1)
     hit = cache.lookup(f.num_nodes, lookup_edges)

@@ -30,7 +30,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.segmentation import SegmentationPlan
+from repro.core.segmentation import SegmentationPlan, plan_segmentation
 
 MAX_ROUNDS = 64          # outer hook-round fuel
 
@@ -474,7 +474,6 @@ def forest_scan_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                            lift_steps: int = 2,
                            max_rounds: int = MAX_ROUNDS,
                            bill_nodes: int | jnp.ndarray | None = None,
-                           segment_size: int = 512,
                            ) -> tuple[jnp.ndarray, jnp.ndarray,
                                       jnp.ndarray, WorkCounters]:
     """Work-efficient drive of the id-recording hook over a packed
@@ -488,7 +487,10 @@ def forest_scan_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     erasing most of the tree-aware path's advantage over the plain
     scoped recompute."""
     cap = packed.shape[0]
-    seg = min(segment_size, cap)
+    # the paper's segments of ~|V|/2 rows: every segment pays a full
+    # O(V) compress, so a fixed small segment over a V-row skeleton
+    # costs V/seg compresses — 4096 per delete tick at |V| = 2^22
+    seg = plan_segmentation(cap, pi.shape[0]).segment_size
     pad = (-cap) % seg
     if pad:
         packed = jnp.concatenate(
@@ -562,13 +564,9 @@ def forest_scoped_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
 
     skel, skel_ids, n_skel = pack_edge_rows(parents, parent_eidx,
                                             forest_keep)
-    # 1024-row segments: fewer scan iterations (the skeleton is V-sized
-    # from the first tick even while the log is still small) at the
-    # same 2-pass billing floor as 512 on the bench fixtures
     pi1, parents1, eidx1, work = forest_scan_rounds_ids(
         pi0, parents0, eidx0, skel, skel_ids, n_skel, work,
-        lift_steps=0, max_rounds=max_rounds, bill_nodes=bill_nodes,
-        segment_size=1024)
+        lift_steps=0, max_rounds=max_rounds, bill_nodes=bill_nodes)
 
     crossing = jnp.logical_and(edge_mask,
                                pi1[edges[:, 0]] != pi1[edges[:, 1]])

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 from _graphgen import edge_lists
 from _propcheck import given, settings, st
 
@@ -224,9 +225,9 @@ def test_pallas_fused_bit_identical_to_jnp_backend():
 
 
 def _subjaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for x in v:
@@ -235,7 +236,7 @@ def _subjaxprs(v):
 
 def _pallas_call_sites(jaxpr) -> int:
     """Static pallas_call call sites in a jaxpr (recursing through
-    pjit/scan/while sub-jaxprs)."""
+    jit/scan/while sub-jaxprs)."""
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":

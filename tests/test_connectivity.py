@@ -196,6 +196,61 @@ def test_autotune_cache_roundtrip_and_override(tmp_path):
     assert reloaded.lookup(4 * g.num_nodes, 64 * g.num_edges) is None
 
 
+def test_autotune_candidates_need_no_pallas_kernel():
+    """No CC Pallas kernel lowers for a TPU, so no autotune candidate
+    may reach one: every traced program of every candidate backend is
+    free of pallas_call."""
+    import repro.api.backends  # noqa: F401 — registers trace entries
+    from repro.analysis.jaxpr_utils import trace, walk_eqns
+    from repro.api.registry import trace_entries
+    for method in policy.AUTOTUNE_METHODS:
+        entries = [e for e in trace_entries() if e.backend == method]
+        assert entries, method
+        for entry in entries:
+            traced = trace(entry, (256, 1024))
+            assert traced.failure is None, (entry.name, traced.failure)
+            prims = {eqn.primitive.name for eqn in walk_eqns(traced.jaxpr)}
+            assert "pallas_call" not in prims, entry.name
+
+
+def test_autotune_cached_pallas_winner_is_dropped(tmp_path):
+    """A cache file naming a winner ``measure`` can no longer record
+    (written before the Pallas backend left the candidate set) must not
+    route anything to it."""
+    path = tmp_path / "autotune.json"
+    key = policy.AutotuneCache.key(1000, 4000)
+    path.write_text(json.dumps({
+        "version": policy.CACHE_FORMAT_VERSION,
+        "entries": {key: {"method": "pallas_fused", "ms": 1.0}}}))
+    cache = policy.AutotuneCache(str(path))
+    assert cache.lookup(1000, 4000) is None
+    assert policy.select_method(1000, 4000, cache=cache) == "adaptive"
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_measure_candidates_ignore_interpret_mode(monkeypatch, interpret):
+    """``measure`` times the same candidate set whether or not Pallas
+    kernels would run interpreted on this host."""
+    import jax.numpy as jnp
+
+    import repro.api
+    import repro.kernels
+    timed = []
+
+    class _Result:
+        labels = jnp.zeros((4,), jnp.int32)
+
+    def fake_solve(edges, num_nodes, method):
+        timed.append(method)
+        return _Result()
+
+    monkeypatch.setattr(repro.kernels, "default_interpret",
+                        lambda: interpret)
+    monkeypatch.setattr(repro.api, "solve", fake_solve)
+    policy.AutotuneCache().measure(np.zeros((3, 2), np.int32), 4, reps=1)
+    assert tuple(dict.fromkeys(timed)) == policy.AUTOTUNE_METHODS
+
+
 def test_autotune_save_is_atomic_and_collision_free(tmp_path):
     """Two caches (standing in for two concurrent ConnectivityService
     processes) interleave saves to one path: every save goes through a
