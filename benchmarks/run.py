@@ -759,7 +759,8 @@ def sampled(scale: float) -> None:
             assert np.array_equal(np.asarray(res[backend].labels),
                                   want), (g.name, backend)
             if backend == "sampled":
-                stats = dict(solver.last_plan.artifacts["sampled_stats"])
+                stats = {k: int(v) for k, v in solver.last_plan
+                         .artifacts["sampled_stats"].items()}
             ms[backend] = _bench(
                 lambda b=backend: solver.solve(backend=b).labels,
                 reps=1 if backend == "sampled_fused" else 2)

@@ -88,9 +88,9 @@ def _run_sampled(plan: ExecutionPlan, fused: bool) -> CCResult:
                                     lift_steps=plan.lift_steps,
                                     fused=fused,
                                     interpret=plan.opts.get("interpret"))
-    # phase-split telemetry (device scalars synced once, post-solve)
-    plan.artifacts["sampled_stats"] = {k: int(v)
-                                       for k, v in res.stats.items()}
+    # phase-split telemetry, left as device scalars: the solve path
+    # syncs nothing, a reader pays for the values it reads
+    plan.artifacts["sampled_stats"] = dict(res.stats)
     return CCResult(res.labels, res.work)
 
 

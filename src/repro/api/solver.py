@@ -146,8 +146,9 @@ class Solver:
                                  "num_nodes")
             g, n = None, int(num_nodes)
         else:
-            g = as_device_graph(graph, num_nodes,
-                                num_segments=num_segments)
+            with obs.span("solver.open", tenant=name):
+                g = as_device_graph(graph, num_nodes,
+                                    num_segments=num_segments)
             n = g.num_nodes
         return cls(g, n, lift_steps=lift_steps, num_segments=num_segments,
                    mesh=mesh, axis_names=axis_names,
@@ -287,6 +288,9 @@ class Solver:
                 with obs.span("solver.solve", tenant=self.name,
                               **plan.trace_tags()):
                     res = plan.run()
+                # device scalars, kept unsynced until counters are read
+                obs.count_device("solve.hook_rounds", res.work.hook_rounds)
+                obs.count_device("solve.jump_sweeps", res.work.jump_sweeps)
             else:
                 res = plan.run()
         self.stats["solves"] += 1

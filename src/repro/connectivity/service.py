@@ -337,8 +337,9 @@ class ConnectivityService:
     # -- telemetry ---------------------------------------------------------
 
     def obs_summary(self) -> dict:
-        """The tick summary: per-tenant/global latency SLOs, always-on
-        host counters (autotune hit/miss, deprecation-shim traffic),
+        """The tick summary: per-tenant/global latency SLOs, host
+        counters (autotune hit/miss, deprecation-shim traffic, and the
+        device counters kept while tracing is on, synced here),
         and the fleet's on-device metrics — merged across tenants with
         ``Metrics.merge`` (associative, so fold order is irrelevant)
         and flushed ONCE through the audited ``queries.to_host`` sink.

@@ -24,6 +24,7 @@ what the batched path uses, one count per graph in the bucket).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, NamedTuple
 
@@ -33,6 +34,20 @@ import jax.numpy as jnp
 from repro.core.segmentation import SegmentationPlan, plan_segmentation
 
 MAX_ROUNDS = 64          # outer hook-round fuel
+
+
+def _scope(name: str):
+    """Trace the decorated phase under ``jax.named_scope(name)``: its
+    ops carry ``name`` in their HLO op_name path (the profiler's
+    ``tf_op``), so every engine that composes the phase inherits the
+    name. Metadata only; the computation is unchanged."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 def compress_fuel(num_nodes: int) -> int:
@@ -75,6 +90,7 @@ class WorkCounters(NamedTuple):
 # Primitive operations
 # ---------------------------------------------------------------------------
 
+@_scope("hook")
 def hook_edges(pi: jnp.ndarray, edges: jnp.ndarray, lift_steps: int = 0
                ) -> jnp.ndarray:
     """One deterministic hook round over ``edges`` (TPU analogue of Hook /
@@ -100,6 +116,7 @@ def jump_once(pi: jnp.ndarray) -> jnp.ndarray:
     return pi[pi]
 
 
+@_scope("compress")
 def compress(pi: jnp.ndarray, work: WorkCounters,
              count_syncs: bool = False,
              bill_nodes: int | jnp.ndarray | None = None,
@@ -251,6 +268,7 @@ def empty_forest(num_nodes: int) -> jnp.ndarray:
     return jnp.full((num_nodes, 2), -1, jnp.int32)
 
 
+@_scope("hook")
 def hook_edges_forest(pi: jnp.ndarray, parents: jnp.ndarray,
                       edges: jnp.ndarray, lift_steps: int = 0
                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -281,6 +299,7 @@ def hook_edges_forest(pi: jnp.ndarray, parents: jnp.ndarray,
     return new_pi, parents
 
 
+@_scope("segment_scan")
 def forest_segment_scan(pi: jnp.ndarray, parents: jnp.ndarray,
                         segments: jnp.ndarray, work: WorkCounters,
                         true_counts: jnp.ndarray,
@@ -303,6 +322,7 @@ def forest_segment_scan(pi: jnp.ndarray, parents: jnp.ndarray,
     return pi, parents, work
 
 
+@_scope("cleanup")
 def forest_cleanup_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
                           edges: jnp.ndarray, work: WorkCounters,
                           true_edges: int | jnp.ndarray | None = None,
@@ -374,6 +394,7 @@ def empty_forest_idx(num_nodes: int) -> jnp.ndarray:
     return jnp.full((num_nodes,), -1, jnp.int32)
 
 
+@_scope("hook")
 def hook_edges_forest_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                           parent_eidx: jnp.ndarray, edges: jnp.ndarray,
                           edge_ids: jnp.ndarray, lift_steps: int = 0,
@@ -402,6 +423,7 @@ def hook_edges_forest_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     return new_pi, parents, parent_eidx
 
 
+@_scope("cleanup")
 def forest_cleanup_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                               parent_eidx: jnp.ndarray,
                               edges: jnp.ndarray, edge_ids: jnp.ndarray,
@@ -438,6 +460,7 @@ def forest_cleanup_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     return pi, parents, parent_eidx, work
 
 
+@_scope("segment_scan")
 def forest_segment_scan_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                             parent_eidx: jnp.ndarray,
                             segments: jnp.ndarray, seg_ids: jnp.ndarray,
@@ -616,6 +639,7 @@ def segment_true_counts(true_edges: int | jnp.ndarray,
 # Round composition (Fig. 4)
 # ---------------------------------------------------------------------------
 
+@_scope("segment_scan")
 def segment_scan(pi: jnp.ndarray, segments: jnp.ndarray, ops: RoundOps,
                  work: WorkCounters,
                  true_counts: jnp.ndarray | None = None,
@@ -648,6 +672,7 @@ def segment_scan(pi: jnp.ndarray, segments: jnp.ndarray, ops: RoundOps,
     return pi, work
 
 
+@_scope("cleanup")
 def cleanup_rounds(pi: jnp.ndarray, edges: jnp.ndarray, ops: RoundOps,
                    work: WorkCounters,
                    true_edges: int | jnp.ndarray | None = None,

@@ -67,45 +67,50 @@ def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds,
     recorded. Returns ``(pi, parents, work, n_sampled, giant_label,
     giant_size)`` — all device values."""
     e = edges.shape[0]
-    # symmetrize so vertices stored only as targets still get sampled;
-    # padded (0, 0) rows stay (0, 0) and are masked out via the true
-    # count below
-    sym = jnp.concatenate([edges, edges[:, ::-1]], axis=0)
-    row_real = jnp.arange(e, dtype=jnp.int32) < true_edges
-    real = jnp.concatenate([row_real, row_real])
-    src = sym[:, 0]
-    order = jnp.argsort(src, stable=True)
-    sorted_src = src[order]
-    neighbors = sym[order, 1]
-    real_sorted = real[order]
-    offsets = jnp.searchsorted(
-        sorted_src, jnp.arange(num_nodes + 1, dtype=jnp.int32)
-    ).astype(jnp.int32)
-    # slot (v, j) = CSR position offsets[v] + j; valid iff inside v's
-    # row AND backed by a true (unpadded) edge
-    slots = offsets[:-1, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-    in_row = slots < offsets[1:, None]
-    slots_c = jnp.minimum(slots, 2 * e - 1)
-    valid = jnp.logical_and(in_row, real_sorted[slots_c])
-    su = jnp.where(valid, jnp.arange(num_nodes,
-                                     dtype=jnp.int32)[:, None], 0)
-    sv = jnp.where(valid, neighbors[slots_c], 0)
-    sampled = jnp.stack([su.reshape(-1), sv.reshape(-1)], axis=-1)
-    n_sampled = jnp.sum(valid).astype(jnp.int32)
+    with jax.named_scope("sampled.csr"):
+        # symmetrize so vertices stored only as targets still get
+        # sampled; padded (0, 0) rows stay (0, 0) and are masked out
+        # via the true count below
+        sym = jnp.concatenate([edges, edges[:, ::-1]], axis=0)
+        row_real = jnp.arange(e, dtype=jnp.int32) < true_edges
+        real = jnp.concatenate([row_real, row_real])
+        src = sym[:, 0]
+        order = jnp.argsort(src, stable=True)
+        sorted_src = src[order]
+        neighbors = sym[order, 1]
+        real_sorted = real[order]
+        offsets = jnp.searchsorted(
+            sorted_src, jnp.arange(num_nodes + 1, dtype=jnp.int32)
+        ).astype(jnp.int32)
 
-    pi = jnp.arange(num_nodes, dtype=jnp.int32)
-    parents = rounds.empty_forest(num_nodes)
-    work = WorkCounters.zeros()
-    bill = n_sampled * (1 + lift_steps)
-    for _ in range(sample_rounds):
-        pi, parents = rounds.hook_edges_forest(pi, parents, sampled,
-                                               lift_steps=lift_steps)
-        work = work.add(hook_ops=bill, hook_rounds=1)
-        pi, work = rounds.compress(pi, work)
+    with jax.named_scope("sampled.sample"):
+        # slot (v, j) = CSR position offsets[v] + j; valid iff inside
+        # v's row AND backed by a true (unpadded) edge
+        slots = offsets[:-1, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
+        in_row = slots < offsets[1:, None]
+        slots_c = jnp.minimum(slots, 2 * e - 1)
+        valid = jnp.logical_and(in_row, real_sorted[slots_c])
+        su = jnp.where(valid, jnp.arange(num_nodes,
+                                         dtype=jnp.int32)[:, None], 0)
+        sv = jnp.where(valid, neighbors[slots_c], 0)
+        sampled = jnp.stack([su.reshape(-1), sv.reshape(-1)], axis=-1)
+        n_sampled = jnp.sum(valid).astype(jnp.int32)
 
-    census = jnp.zeros((num_nodes,), jnp.int32).at[pi].add(1)
-    giant = jnp.argmax(census).astype(jnp.int32)
-    return pi, parents, work, n_sampled, giant, census[giant]
+        pi = jnp.arange(num_nodes, dtype=jnp.int32)
+        parents = rounds.empty_forest(num_nodes)
+        work = WorkCounters.zeros()
+        bill = n_sampled * (1 + lift_steps)
+        for _ in range(sample_rounds):
+            pi, parents = rounds.hook_edges_forest(pi, parents, sampled,
+                                                   lift_steps=lift_steps)
+            work = work.add(hook_ops=bill, hook_rounds=1)
+            pi, work = rounds.compress(pi, work)
+
+    with jax.named_scope("sampled.census"):
+        census = jnp.zeros((num_nodes,), jnp.int32).at[pi].add(1)
+        giant = jnp.argmax(census).astype(jnp.int32)
+        giant_size = census[giant]
+    return pi, parents, work, n_sampled, giant, giant_size
 
 
 @functools.partial(
@@ -119,11 +124,13 @@ def _residue_scan_jit(edges, true_edges, pi, parents, work, *,
     to a prefix and billed as a traced count. Starts from the sampled
     labels (NOT identity). Returns ``(pi, parents, work, n_residue)``."""
     e = edges.shape[0]
-    row_real = jnp.arange(e, dtype=jnp.int32) < true_edges
-    live = jnp.logical_and(pi[edges[:, 0]] != pi[edges[:, 1]], row_real)
-    n_res = jnp.sum(live).astype(jnp.int32)
-    order = jnp.argsort(~live, stable=True)       # residue rows first
-    packed = jnp.where(live[order][:, None], edges[order], 0)
+    with jax.named_scope("sampled.pack"):
+        row_real = jnp.arange(e, dtype=jnp.int32) < true_edges
+        live = jnp.logical_and(pi[edges[:, 0]] != pi[edges[:, 1]],
+                               row_real)
+        n_res = jnp.sum(live).astype(jnp.int32)
+        order = jnp.argsort(~live, stable=True)   # residue rows first
+        packed = jnp.where(live[order][:, None], edges[order], 0)
     plan = plan_segmentation(e, num_nodes, num_segments)
     segments = rounds.pad_and_segment(packed, plan)
     counts = rounds.segment_true_counts(n_res, plan)
@@ -157,7 +164,8 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
     through the ``cc_fused`` Pallas kernel (no forest recording on the
     residue — ``sampled_fused`` reports ``spanning_forest=False``).
     Each phase runs under its own ``repro.obs`` span, and the
-    sampled-vs-residue work split lands in ``SampledResult.stats``.
+    sampled-vs-residue work split lands in ``SampledResult.stats``
+    (device scalars; nothing here syncs).
     """
     from repro.graphs.device import as_device_graph
     from repro.obs import trace as obs
@@ -199,9 +207,9 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
              "residue_hook_ops": work.hook_ops - s_work.hook_ops,
              "n_sampled": n_sampled, "n_residue": n_res,
              "giant_label": giant, "giant_size": giant_size}
-    # always-on host counters: the sampled-vs-residue work split is
-    # part of obs_summary() whether or not span tracing is enabled
-    obs.count("sampled.solves")
-    obs.count("sampled.hook_ops.sample", int(stats["sample_hook_ops"]))
-    obs.count("sampled.hook_ops.residue", int(stats["residue_hook_ops"]))
+    # the sampled-vs-residue work split, kept as device scalars while
+    # tracing is on (no sync here; read through tracer().counters)
+    obs.count_device("sampled.solves", 1)
+    obs.count_device("sampled.hook_ops.sample", stats["sample_hook_ops"])
+    obs.count_device("sampled.hook_ops.residue", stats["residue_hook_ops"])
     return SampledResult(pi, parents, work, stats)

@@ -50,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.batch import next_pow2
 from repro.core.segmentation import (SegmentationPlan, adaptive_num_segments,
                                      plan_segmentation)
+from repro.obs import trace as obs
 
 _MIN_PAD_ROWS = 8
 
@@ -161,12 +162,17 @@ class DeviceGraph:
             if device is not None:
                 edges = jax.device_put(edges, device)
         else:
-            host = np.asarray(edges, np.int32).reshape(-1, 2)
+            # host ingest spans; ``ingest.put`` times the call, which
+            # may return before the copy to the device ends
+            with obs.span("ingest.convert"):
+                host = np.asarray(edges, np.int32).reshape(-1, 2)
             t = true_edges if isinstance(true_edges, (int, np.integer)) \
                 else host.shape[0]
-            degree_skew = measure_degree_skew(host[:int(t)],
-                                              int(num_nodes))
-            edges = jax.device_put(host, device)
+            with obs.span("ingest.skew"):
+                degree_skew = measure_degree_skew(host[:int(t)],
+                                                  int(num_nodes))
+            with obs.span("ingest.put"):
+                edges = jax.device_put(host, device)
         e_stored = int(edges.shape[0])
         if true_edges is None:
             true_edges = e_stored

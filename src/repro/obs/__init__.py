@@ -6,10 +6,13 @@ Three cooperating layers, all opt-in and all bounded:
 * **Span tracing** (``obs.trace``): host-side ``span(...)`` context
   managers around every facade/service operation, tagged with plan
   provenance (backend, bucket, forced/autotune/heuristic) and tenant
-  id; a fixed-capacity ring buffer of finished spans; JSON-lines and
-  Chrome ``trace_event`` (Perfetto) exporters; an opt-in
-  ``jax.profiler`` annotation bridge. Disabled (the default) it costs
-  one flag check per call site.
+  id; a fixed-capacity ring buffer of finished spans, each stamped
+  ``ts_ns`` on the profiler's clock with its ``id`` and ``parent``;
+  JSON-lines and Chrome ``trace_event`` (Perfetto) exporters that line
+  up with a device profile; an opt-in ``jax.profiler`` annotation
+  bridge; ``count_device`` counters that keep device scalars and sync
+  only when read. Disabled (the default) it costs one flag check per
+  call site and syncs nothing.
 * **On-device metrics** (``obs.metrics``): a ``Metrics`` pytree of
   int32 counters + fixed-bucket histograms threaded through the
   absorb/delete jits like ``WorkCounters`` — the instrumented
@@ -28,13 +31,13 @@ from repro.obs.metrics import (COUNTERS, HIST_KINDS, WORK_SPEC,
                                record_mutation, record_rebuild)
 from repro.obs.slo import (DEFAULT_LATENCY_SPEC, LatencyHistogram,
                            SLORecorder, merge_recorders)
-from repro.obs.trace import (EventLog, Span, Tracer, count, disable,
-                             enable, enabled, span, tracer)
+from repro.obs.trace import (EventLog, Span, Tracer, count, count_device,
+                             disable, enable, enabled, span, tracer)
 
 __all__ = [
     # trace
-    "span", "count", "enable", "disable", "enabled", "tracer",
-    "Tracer", "Span", "EventLog",
+    "span", "count", "count_device", "enable", "disable", "enabled",
+    "tracer", "Tracer", "Span", "EventLog",
     # metrics
     "Metrics", "HistogramSpec", "WORK_SPEC", "COUNTERS", "HIST_KINDS",
     "record_mutation", "record_rebuild", "flush",

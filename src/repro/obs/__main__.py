@@ -1,7 +1,8 @@
 """CLI for trace files: ``python -m repro.obs summary <trace.jsonl>``
-renders per-span-name latency aggregates + counters from a JSON-lines
-export; ``python -m repro.obs perfetto <trace.jsonl> <out.json>``
-converts one to the Chrome ``trace_event`` format for the Perfetto UI.
+renders per-span-name latency aggregates (total and self time) +
+counters from a JSON-lines export; ``python -m repro.obs perfetto
+<trace.jsonl> <out.json>`` converts one to the Chrome ``trace_event``
+format for the Perfetto UI.
 """
 from __future__ import annotations
 
@@ -37,11 +38,11 @@ def cmd_summary(path: str) -> int:
     else:
         name_w = max(len(n) for n in summ) + 2
         print(f"{'span':<{name_w}}{'count':>8}{'total_ms':>12}"
-              f"{'p50_us':>10}{'p99_us':>12}")
+              f"{'self_ms':>12}{'p50_us':>10}{'p99_us':>12}")
         for name, row in summ.items():
             print(f"{name:<{name_w}}{row['count']:>8}"
-                  f"{row['total_ms']:>12.3f}{row['p50_us']:>10.1f}"
-                  f"{row['p99_us']:>12.1f}")
+                  f"{row['total_ms']:>12.3f}{row['self_ms']:>12.3f}"
+                  f"{row['p50_us']:>10.1f}{row['p99_us']:>12.1f}")
     if dropped:
         print(f"\n({dropped} oldest spans dropped by the ring buffer)")
     if counters:

@@ -23,6 +23,14 @@ Design constraints, in priority order:
    profiles line up with host spans when a profiler session is active,
    and costs nothing otherwise.
 
+Every record carries ``ts_ns``, its start on the clock the profiler
+stamps a ``TraceAnnotation`` with (``time.time_ns``, the wall clock),
+so records line up with a device profile even when read from the log
+or an export rather than through the annotation mirror; ``dur_us``
+comes from ``perf_counter_ns``. Each record also has an ``id`` and the
+``parent`` id of the span that was open around it (None at the top),
+from which ``span_summary`` computes self time.
+
 Host **counters** (``count(name)``) are always on — they are plain
 dict increments used for process-wide facts that must not depend on
 when ``enable()`` was called: autotune cache hits/misses
@@ -30,11 +38,18 @@ when ``enable()`` was called: autotune cache hits/misses
 (``repro._deprecation``). They surface in
 ``ConnectivityService.obs_summary()`` and the JSONL export.
 
+**Device counters** (``count_device(name, scalar)``) count what only a
+device value knows (sweeps a solve ran, hook work billed) without a
+sync on the hot path: off, they do nothing; on, the tracer keeps the
+device scalar and adds it to ``counters`` as a Python int only when
+``counters`` or the JSONL export is read.
+
 Exports: ``export_jsonl`` writes one JSON object per span (plus a
 trailing ``counters`` record); ``export_chrome_trace`` writes the
-Chrome ``trace_event`` format (complete "X" events, µs timebase) —
-loadable directly in Perfetto / chrome://tracing. ``python -m
-repro.obs`` renders either.
+Chrome ``trace_event`` format (complete "X" events, µs on the
+profiler's clock) — loadable directly in Perfetto / chrome://tracing,
+beside a device profile of the same run. ``python -m repro.obs``
+renders either.
 """
 from __future__ import annotations
 
@@ -43,6 +58,7 @@ import time
 from typing import Optional
 
 _ENABLED = False        # THE module-level fast-path flag (see enable())
+PENDING_CAP = 4096      # device scalars count_device keeps before folding
 
 
 class _NullSpan:
@@ -116,8 +132,8 @@ class Span:
     ``tag(...)`` attaches facts learned mid-span (the policy route, the
     retired-request count) before it closes."""
 
-    __slots__ = ("name", "tenant", "step", "tags", "depth",
-                 "_tracer", "_t0_ns", "_annotation")
+    __slots__ = ("name", "tenant", "step", "tags", "depth", "id",
+                 "parent", "_tracer", "_t0_ns", "_ts_ns", "_annotation")
     enabled = True
 
     def __init__(self, tracer: "Tracer", name: str,
@@ -128,8 +144,11 @@ class Span:
         self.step = step
         self.tags = tags
         self.depth = 0
+        self.id = 0
+        self.parent = None
         self._tracer = tracer
         self._t0_ns = 0
+        self._ts_ns = 0
         self._annotation = None
 
     def tag(self, **tags) -> "Span":
@@ -139,11 +158,15 @@ class Span:
     def __enter__(self) -> "Span":
         t = self._tracer
         self.depth = len(t._stack)
+        self.parent = t._stack[-1].id if t._stack else None
+        t._next_id += 1
+        self.id = t._next_id
         t._stack.append(self)
         ann = t._annotation_for(self.name, self.step)
         if ann is not None:
             ann.__enter__()
             self._annotation = ann
+        self._ts_ns = time.time_ns()
         self._t0_ns = time.perf_counter_ns()
         return self
 
@@ -154,8 +177,9 @@ class Span:
             self._annotation.__exit__(exc_type, exc, tb)
         if t._stack and t._stack[-1] is self:
             t._stack.pop()
-        rec = {"name": self.name,
-               "ts_us": round((self._t0_ns - t._epoch_ns) / 1e3, 3),
+        rec = {"name": self.name, "id": self.id, "parent": self.parent,
+               "ts_ns": self._ts_ns,
+               "ts_us": round(self._ts_ns / 1e3, 3),
                "dur_us": round(dur_ns / 1e3, 3),
                "depth": self.depth}
         if self.tenant is not None:
@@ -175,12 +199,14 @@ class Tracer:
 
     def __init__(self, capacity: int = 4096):
         self.log = EventLog(capacity)
-        self.counters: dict[str, int] = {}
+        self._counters: dict[str, int] = {}
+        self._pending: list = []           # (name, device scalar)
         self._stack: list = []
-        self._epoch_ns = time.perf_counter_ns()
+        self._next_id = 0
         self._annotate = False
         self._trace_annotation = None      # jax.profiler classes, lazy
         self._step_annotation = None
+        self._metadata_key_was = None      # jax config before annotations
 
     # -- span / counter entry points ----------------------------------------
 
@@ -189,7 +215,30 @@ class Tracer:
         return Span(self, name, tenant, step, tags)
 
     def count(self, name: str, n: int = 1) -> None:
-        self.counters[name] = self.counters.get(name, 0) + n
+        self._counters[name] = self._counters.get(name, 0) + n
+
+    def count_device(self, name: str, value) -> None:
+        """Keep ``value`` (a device scalar, or an int) for ``name``;
+        it is added to ``counters`` when they are read. Past
+        ``PENDING_CAP`` kept values the oldest are folded in then, so
+        a long traced run holds no more than that many."""
+        self._pending.append((name, value))
+        if len(self._pending) > PENDING_CAP:
+            self._fold(self._pending[:PENDING_CAP // 2])
+            del self._pending[:PENDING_CAP // 2]
+
+    def _fold(self, pending: list) -> None:
+        for name, value in pending:
+            self.count(name, int(value))
+
+    @property
+    def counters(self) -> dict:
+        """Host and device counters by name, as Python ints. Reading
+        them syncs any device scalar still kept."""
+        if self._pending:
+            self._fold(self._pending)
+            self._pending.clear()
+        return self._counters
 
     def _annotation_for(self, name: str, step: Optional[int]):
         """The jax.profiler bridge: host spans double as device-profile
@@ -205,25 +254,45 @@ class Tracer:
         return None
 
     def enable_jax_annotations(self) -> None:
+        import jax
         from jax.profiler import StepTraceAnnotation, TraceAnnotation
         self._trace_annotation = TraceAnnotation
         self._step_annotation = StepTraceAnnotation
         self._annotate = True
+        # the persistent compile cache keys programs without their op
+        # metadata by default, so an executable it loads keeps the op
+        # names (named scopes) of whichever build compiled it first and
+        # a profile would show those; key on metadata while profiles
+        # are wanted (restored by disable())
+        if self._metadata_key_was is None:
+            self._metadata_key_was = \
+                jax.config.jax_compilation_cache_include_metadata_in_key
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+
+    def disable_jax_annotations(self) -> None:
+        self._annotate = False
+        if self._metadata_key_was is not None:
+            import jax
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key",
+                self._metadata_key_was)
+            self._metadata_key_was = None
 
     def reset(self) -> None:
-        """Forget events, counters, and the open-span stack; restart
-        the trace epoch (test/benchmark hook)."""
+        """Forget events, counters (kept device scalars too), and the
+        open-span stack (test/benchmark hook)."""
         self.log.clear()
-        self.counters.clear()
+        self._counters.clear()
+        self._pending.clear()
         self._stack.clear()
-        self._epoch_ns = time.perf_counter_ns()
 
     # -- exporters ----------------------------------------------------------
 
     def export_jsonl(self, path: str) -> None:
         """JSON-lines: one ``{"type": "span", ...}`` object per event,
         plus one trailing ``{"type": "counters", ...}`` record carrying
-        the host counters and the ring-buffer drop count."""
+        the host and device counters and the ring-buffer drop count."""
         with open(path, "w") as fh:
             for ev in self.log.events():
                 fh.write(json.dumps({"type": "span", **ev}) + "\n")
@@ -235,14 +304,16 @@ class Tracer:
     def export_chrome_trace(self, path: str) -> None:
         """Chrome ``trace_event`` JSON (Perfetto-loadable): complete
         "X" events on one thread track — nesting comes from ts/dur
-        containment, tags ride in ``args``."""
+        containment, tags ride in ``args``; ``ts`` is on the profiler's
+        clock, so the file lines up with a device profile."""
         with open(path, "w") as fh:
             json.dump(chrome_trace_events(self.log.events()), fh)
 
     def summary(self) -> dict:
         """Per-span-name aggregates over the retained events:
-        ``{name: {count, total_ms, p50_us, p99_us}}`` (percentiles are
-        exact over the retained window — the ring buffer bounds it)."""
+        ``{name: {count, total_ms, self_ms, p50_us, p99_us}}``
+        (percentiles are exact over the retained window — the ring
+        buffer bounds it)."""
         return span_summary(self.log.events())
 
 
@@ -265,10 +336,26 @@ def chrome_trace_events(events: list) -> dict:
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
+def self_times_us(events: list) -> list:
+    """Each event's duration less the time its children cover (the
+    events whose ``parent`` is its ``id``), in µs, in event order.
+    Records without ids (older exports) count their whole duration."""
+    children: dict = {}
+    for ev in events:
+        if ev.get("parent") is not None:
+            children[ev["parent"]] = children.get(ev["parent"], 0.0) \
+                + ev["dur_us"]
+    return [ev["dur_us"] - children.get(ev.get("id"), 0.0)
+            if ev.get("id") is not None else ev["dur_us"]
+            for ev in events]
+
+
 def span_summary(events: list) -> dict:
     by_name: dict[str, list] = {}
-    for ev in events:
+    own: dict[str, float] = {}
+    for ev, self_us in zip(events, self_times_us(events)):
         by_name.setdefault(ev["name"], []).append(ev["dur_us"])
+        own[ev["name"]] = own.get(ev["name"], 0.0) + self_us
     out = {}
     for name in sorted(by_name):
         durs = sorted(by_name[name])
@@ -276,6 +363,7 @@ def span_summary(events: list) -> dict:
         pct = lambda q: durs[min(n - 1, int(q * (n - 1) + 0.5))]  # noqa: E731
         out[name] = {"count": n,
                      "total_ms": round(sum(durs) / 1e3, 3),
+                     "self_ms": round(own[name] / 1e3, 3),
                      "p50_us": round(pct(0.50), 1),
                      "p99_us": round(pct(0.99), 1)}
     return out
@@ -302,7 +390,9 @@ def enable(*, capacity: int | None = None,
     """Turn span tracing on. ``capacity`` resizes the ring buffer
     (clearing it); ``jax_annotations=True`` additionally mirrors every
     span into ``jax.profiler`` annotations so device profiles line up
-    with host spans. Host counters are unaffected (always on)."""
+    with host spans, and keys the persistent compile cache on op
+    metadata so profiled programs carry this build's named scopes.
+    Host counters are unaffected (always on)."""
     global _ENABLED
     if capacity is not None and capacity != _TRACER.log.capacity:
         _TRACER.log = EventLog(capacity)
@@ -317,7 +407,7 @@ def disable() -> None:
     counters are kept — export or ``tracer().reset()`` as needed."""
     global _ENABLED
     _ENABLED = False
-    _TRACER._annotate = False
+    _TRACER.disable_jax_annotations()
 
 
 def span(name: str, tenant: Optional[str] = None,
@@ -335,3 +425,11 @@ def span(name: str, tenant: Optional[str] = None,
 def count(name: str, n: int = 1) -> None:
     """Bump a host counter (always on — independent of ``enable()``)."""
     _TRACER.count(name, n)
+
+
+def count_device(name: str, value) -> None:
+    """Add a device scalar to a counter without a sync: nothing while
+    tracing is off; on, the scalar is kept and read (as an int) only
+    when ``tracer().counters`` or the JSONL export is."""
+    if _ENABLED:
+        _TRACER.count_device(name, value)

@@ -11,6 +11,7 @@ all enabled) still performs zero host transfers under
 ``jax.transfer_guard("disallow")``.
 """
 import json
+import time
 import warnings
 
 import numpy as np
@@ -181,6 +182,88 @@ def test_span_nesting_depth_tags_and_order():
     assert outer["dur_us"] >= inner["dur_us"]
 
 
+def test_span_records_ids_parents_and_self_time():
+    """Each record names the span open around it; ``span_summary``
+    takes the children's time off the parent's (choosing-metrics §4)."""
+    obs.enable()
+    with obs.span("outer"):
+        with obs.span("a"):
+            time.sleep(0.002)
+        with obs.span("b"):
+            with obs.span("c"):
+                pass
+    evs = {e["name"]: e for e in obs.tracer().log.events()}
+    assert evs["outer"]["parent"] is None
+    assert evs["a"]["parent"] == evs["b"]["parent"] == evs["outer"]["id"]
+    assert evs["c"]["parent"] == evs["b"]["id"]
+    assert len({e["id"] for e in evs.values()}) == 4
+    assert all(e["ts_us"] == pytest.approx(e["ts_ns"] / 1e3)
+               for e in evs.values())
+    summ = obs.tracer().summary()
+    own = evs["outer"]["dur_us"] - evs["a"]["dur_us"] - evs["b"]["dur_us"]
+    assert summ["outer"]["self_ms"] == pytest.approx(own / 1e3, abs=2e-3)
+    assert summ["b"]["self_ms"] == pytest.approx(
+        (evs["b"]["dur_us"] - evs["c"]["dur_us"]) / 1e3, abs=2e-3)
+    assert summ["a"]["self_ms"] == summ["a"]["total_ms"]
+
+
+def test_span_ts_ns_is_on_the_profilers_clock(tmp_path):
+    """A record's ``ts_ns`` lies within 1 ms of its mirrored annotation
+    in a profile captured around it (the trace's times count from its
+    ``profile_start_time``)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    obs.enable(jax_annotations=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            with obs.span("obs.clock_probe"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    profile = ProfileData.from_file(path)
+    start = dict(profile.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    marks = sorted(start + ev.start_ns for plane in profile.planes
+                   for line in plane.lines for ev in line.events
+                   if ev.name == "obs.clock_probe")
+    recs = sorted(e["ts_ns"] for e in obs.tracer().log.events())
+    assert len(marks) == len(recs) == 3
+    assert all(abs(m - r) < 1e6 for m, r in zip(marks, recs))
+
+
+def test_count_device_keeps_device_scalars_until_read():
+    """Off, a device counter does nothing; on, it keeps the scalar with
+    no transfer and folds it into ``counters`` as an int when read."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.int32(5) + 2
+    obs.count_device("t.sweeps", x)
+    assert "t.sweeps" not in obs.tracer().counters
+    obs.enable()
+    with jax.transfer_guard("disallow"):
+        obs.count_device("t.sweeps", x)
+        obs.count_device("t.sweeps", x)
+        obs.count_device("t.calls", 1)
+    assert obs.tracer().counters == {"t.sweeps": 14, "t.calls": 1}
+    assert type(obs.tracer().counters["t.sweeps"]) is int
+
+
+def test_count_device_folds_its_oldest_values_past_the_cap(monkeypatch):
+    from repro.obs import trace
+    monkeypatch.setattr(trace, "PENDING_CAP", 8)
+    obs.enable()
+    for _ in range(9):
+        obs.count_device("t.n", 1)
+    assert len(obs.tracer()._pending) == 5
+    assert obs.tracer()._counters == {"t.n": 4}
+    assert obs.tracer().counters == {"t.n": 9}
+
+
 def test_span_records_error_and_unwinds_stack():
     obs.enable()
     with pytest.raises(RuntimeError):
@@ -193,14 +276,23 @@ def test_span_records_error_and_unwinds_stack():
 
 def test_jax_profiler_annotation_bridge_smoke():
     """Opt-in bridge constructs real jax.profiler annotations (no
-    profiler session active — they must be harmless no-ops)."""
+    profiler session active — they must be harmless no-ops), and keys
+    the persistent compile cache on op metadata until disabled, so a
+    cached executable cannot bring another build's scope names into a
+    profile."""
+    import jax
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
     obs.enable(jax_annotations=True)
+    assert getattr(jax.config, key) is True
     with obs.span("annotated"):
         pass
     with obs.span("stepped", step=3):    # StepTraceAnnotation path
         pass
     assert [e["name"] for e in obs.tracer().log.events()] == \
         ["annotated", "stepped"]
+    obs.disable()
+    assert getattr(jax.config, key) == before
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +398,26 @@ def test_solver_solve_span_tags_carry_plan_provenance():
     assert {"policy.select", "plan.run"} <= names
 
 
+def test_solver_open_spans_its_host_ingest_and_solve_counts_work():
+    """``solver.open`` encloses the ingest steps of a host graph, and a
+    traced solve keeps its hook rounds and sweeps as device counters."""
+    from repro.api import Solver
+    obs.enable()
+    s = Solver.open(np.array([[0, 1], [1, 2], [4, 5]]), num_nodes=8,
+                    name="o")
+    res = s.solve("adaptive")
+    evs = obs.tracer().log.events()
+    (op,) = [e for e in evs if e["name"] == "solver.open"]
+    assert op["tenant"] == "o"
+    ingest = [e for e in evs if e["name"].startswith("ingest.")]
+    assert [e["name"] for e in ingest] == \
+        ["ingest.convert", "ingest.skew", "ingest.put"]
+    assert all(e["parent"] == op["id"] for e in ingest)
+    c = obs.tracer().counters
+    assert c["solve.hook_rounds"] == int(res.work.hook_rounds) > 0
+    assert c["solve.jump_sweeps"] == int(res.work.jump_sweeps) > 0
+
+
 def test_solver_mutation_spans_and_device_metrics():
     from repro.api import Solver
     obs.enable()
@@ -346,6 +458,66 @@ def test_query_spans_cover_all_kinds():
     for kind in ("same_component", "component_size", "num_components",
                  "component_histogram"):
         assert f"solver.query.{kind}" in names
+
+
+def _lowered_text(program: str) -> str:
+    import jax.numpy as jnp
+
+    from repro.core.cc import _cc_jit
+    from repro.core.rounds import WorkCounters
+    from repro.core.sampled import _residue_scan_jit, _sample_phase_jit
+    edges, true = jnp.zeros((64, 2), jnp.int32), jnp.int32(64)
+    if program == "cc_adaptive":
+        lowered = _cc_jit.lower(edges, None, num_nodes=32,
+                                method="adaptive", num_segments=4,
+                                lift_steps=2)
+    elif program == "sample_phase":
+        lowered = _sample_phase_jit.lower(edges, true, num_nodes=32, k=2,
+                                          sample_rounds=2, lift_steps=2)
+    else:
+        lowered = _residue_scan_jit.lower(
+            edges, true, jnp.arange(32, dtype=jnp.int32),
+            jnp.full((32, 2), -1, jnp.int32), WorkCounters.zeros(),
+            num_nodes=32, num_segments=4, lift_steps=2, fused=False,
+            interpret=True)
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("cc_adaptive", {"segment_scan", "cleanup", "hook", "compress"}),
+    ("sample_phase", {"sampled.csr", "sampled.sample", "sampled.census",
+                      "hook", "compress"}),
+    ("residue_scan", {"sampled.pack", "segment_scan", "cleanup", "hook",
+                      "compress"})])
+def test_round_phases_carry_named_scopes_in_hlo(program, scopes):
+    """The phases' named scopes reach the op names of the
+    lowered programs, where the profiler's ``tf_op`` paths come from."""
+    import re
+    names = set(re.findall(r'loc\("([^"]+)"', _lowered_text(program)))
+    parts = {part for name in names for part in name.split("/")}
+    assert scopes <= parts, scopes - parts
+
+
+@pytest.mark.parametrize("method", ["sampled", "adaptive", "auto"])
+def test_static_solve_syncs_nothing_with_tracing_off(method):
+    """Solver.open -> solve on a host graph copies host to device and
+    reads nothing back while tracing is off: no ``int()``, no
+    ``device_get`` of a device value on the path."""
+    import jax
+
+    from _graphgen import power_law
+    from repro.api import Solver
+    from repro.graphs.device import DeviceGraph
+    n = 2048
+    edges = np.asarray(power_law(n, 16384, seed=5), np.int32)
+    want = np.asarray(Solver.open(edges, n).solve(method).labels)  # warm
+    assert not obs.enabled()
+    with jax.transfer_guard_device_to_host("disallow"):
+        s = Solver.open(DeviceGraph.from_edges(edges, n))
+        labels = s.solve(method).labels
+    np.testing.assert_array_equal(np.asarray(labels), want)
+    if method != "adaptive":
+        assert s.last_method == "sampled"
 
 
 # ---------------------------------------------------------------------------

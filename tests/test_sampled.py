@@ -15,8 +15,8 @@ forest product. This module pins what is SPECIFIC to sampling:
 * the acceptance criterion — ``sampled`` total hook_ops <= half the
   ``jnp`` adaptive backend's on a power-law stand-in, labels identical;
 * the ``repro.obs`` work split (``sampled.hook_ops.sample`` /
-  ``.residue`` always-on counters) and the per-plan
-  ``sampled_stats`` artifact.
+  ``.residue`` device counters, kept while tracing is on) and the
+  per-plan ``sampled_stats`` artifact.
 """
 import numpy as np
 import pytest
@@ -158,15 +158,25 @@ def test_sampled_stats_artifact_shows_phase_split():
 
 
 def test_sampled_obs_counters_record_work_split():
+    """With tracing on, a sampled solve keeps its work split as device
+    counters, read back as ints that sum to the solve's hook work."""
     from repro.obs import trace as obs
-    before = dict(obs.tracer().counters)
-    solve(_powerlaw_edges(256, 1024, seed=9), 256, backend="sampled")
-    counters = obs.tracer().counters
-    for key in ("sampled.solves", "sampled.hook_ops.sample"):
-        assert counters.get(key, 0) > before.get(key, 0), key
+    obs.tracer().reset()
+    obs.enable()
+    try:
+        res = solve(_powerlaw_edges(256, 1024, seed=9), 256,
+                    backend="sampled")
+        counters = dict(obs.tracer().counters)
+    finally:
+        obs.disable()
+        obs.tracer().reset()
+    assert counters["sampled.solves"] == 1
+    assert counters["sampled.hook_ops.sample"] > 0
     # the residue side may legitimately bill 0 (the sampling phase can
     # fully collapse a small graph) but the counter must be surfaced
-    assert "sampled.hook_ops.residue" in counters
+    assert counters["sampled.hook_ops.sample"] \
+        + counters["sampled.hook_ops.residue"] == int(res.work.hook_ops)
+    assert all(type(v) is int for v in counters.values())
 
 
 def test_sampled_fused_matches_sampled_labels_and_counters():
