@@ -713,7 +713,7 @@ def fused(scale: float) -> None:
             backend="pallas", interpret=True).labels, reps=1)
         t_fused = _bench(lambda: _cc_fused_jit(
             ej, None, num_nodes=n, num_segments=plan.num_segments,
-            lift_steps=2, interpret=True).labels, reps=1)
+            interpret=True).labels, reps=1)
         launches_old = plan.num_segments + scan_sweeps
         rows.append({
             "graph": g.name, "nodes": n, "edges": g.num_edges,

@@ -45,8 +45,7 @@ def _service_specs():
                 DeviceGraph.from_edges(edges_b, v),
             ]).pad_pow2()
             return inc_mod._absorb_jit(
-                pi, batch.edges, batch.true_edges_device(), version,
-                lift_steps=2)
+                pi, batch.edges, batch.true_edges_device(), version)
         return (fn,
                 (jax.ShapeDtypeStruct((v,), jnp.int32),
                  jax.ShapeDtypeStruct((half, 2), jnp.int32),
@@ -68,7 +67,7 @@ def _service_specs():
             return inc_mod._delete_jit(
                 edges, alive, pi, batch.edges,
                 batch.true_edges_device(), version, deleted,
-                lift_steps=2, num_segments=adaptive_num_segments(e, v),
+                num_segments=adaptive_num_segments(e, v),
                 scan_method="jnp", interpret=True)
         return (fn,
                 (jax.ShapeDtypeStruct((e, 2), jnp.int32),
@@ -97,8 +96,7 @@ def _service_specs():
             ]).pad_pow2()
             return inc_mod._delete_forest_jit(
                 edges, alive, pi, parents, parent_eidx, batch.edges,
-                batch.true_edges_device(), version, deleted, routes,
-                lift_steps=2)
+                batch.true_edges_device(), version, deleted, routes)
         return (fn,
                 (jax.ShapeDtypeStruct((e, 2), jnp.int32),
                  jax.ShapeDtypeStruct((e,), jnp.bool_),
@@ -164,7 +162,7 @@ def _obs_specs():
             ]).pad_pow2()
             true_count = batch.true_edges_device()
             pi1, version1, work = inc_mod._absorb_jit(
-                pi, batch.edges, true_count, version, lift_steps=2)
+                pi, batch.edges, true_count, version)
             metrics = obs_metrics.record_mutation(
                 metrics, work, true_count, version, version1,
                 kind="insert")
@@ -189,8 +187,7 @@ def _obs_specs():
             true_count = batch.true_edges_device()
             pi1, alive1, version1, deleted1, work = inc_mod._delete_jit(
                 edges, alive, pi, batch.edges, true_count, version,
-                deleted, lift_steps=2,
-                num_segments=adaptive_num_segments(e, v),
+                deleted, num_segments=adaptive_num_segments(e, v),
                 scan_method="jnp", interpret=True)
             metrics = obs_metrics.record_mutation(
                 metrics, work, true_count, version, version1,

@@ -43,8 +43,7 @@ def _register_jnp_variant(method: str) -> None:
                      spanning_forest=method in cc_mod.FOREST_METHODS))
     def _run(plan: ExecutionPlan, _method=method) -> CCResult:
         return cc_mod.solve_static(plan.graph, method=_method,
-                                   num_segments=plan.num_segments,
-                                   lift_steps=plan.lift_steps)
+                                   num_segments=plan.num_segments)
 
 
 for _m in cc_mod.METHODS:       # soman multijump atomic_hook adaptive labelprop
@@ -61,8 +60,7 @@ def _pallas_fused(plan: ExecutionPlan) -> CCResult:
     """The whole Fig. 4 segment scan in ONE pallas_call (DESIGN.md §8);
     labels AND counters bit-identical to the jnp adaptive composition."""
     return cc_mod.solve_static(plan.graph, method=cc_mod.FUSED_METHOD,
-                               num_segments=plan.num_segments,
-                               lift_steps=plan.lift_steps)
+                               num_segments=plan.num_segments)
 
 
 @register_backend("pallas", Capabilities(static=True,
@@ -72,7 +70,6 @@ def _pallas_per_round(plan: ExecutionPlan) -> CCResult:
     sweep). Labels only — counters are zeros by contract."""
     labels = cc_mod.solve_pallas(plan.graph,
                                  num_segments=plan.num_segments,
-                                 lift_steps=plan.lift_steps,
                                  interpret=plan.opts.get("interpret"))
     return CCResult(labels, WorkCounters.zeros())
 
@@ -85,7 +82,6 @@ def _run_sampled(plan: ExecutionPlan, fused: bool) -> CCResult:
     from repro.core import sampled as sampled_mod
     res = sampled_mod.solve_sampled(plan.graph,
                                     num_segments=plan.num_segments,
-                                    lift_steps=plan.lift_steps,
                                     fused=fused,
                                     interpret=plan.opts.get("interpret"))
     # phase-split telemetry, left as device scalars: the solve path
@@ -149,8 +145,7 @@ def _batched(plan: ExecutionPlan) -> list[CCResult]:
     """Shape-bucketed vmapped engine; one ``CCResult`` per input graph,
     bit-identical to per-graph adaptive runs."""
     return batch_mod.solve_batched(plan.graphs,
-                                   num_segments=plan.num_segments,
-                                   lift_steps=plan.lift_steps)
+                                   num_segments=plan.num_segments)
 
 
 # ---------------------------------------------------------------------------
@@ -165,11 +160,12 @@ class _Incremental:
 
     def make_state(self, num_nodes: int, *, lift_steps: int = 2,
                    scan_method: str | None = None) -> IncrementalCC:
-        return IncrementalCC(num_nodes, lift_steps=lift_steps)
+        """``lift_steps`` is accepted and has no effect: every engine
+        hooks flat labels."""
+        return IncrementalCC(num_nodes)
 
     def run(self, plan: ExecutionPlan) -> CCResult:
-        state = self.make_state(plan.num_nodes,
-                                lift_steps=plan.lift_steps)
+        state = self.make_state(plan.num_nodes)
         state.insert_graph(plan.graph)
         return CCResult(state.labels, WorkCounters(**state.work))
 
@@ -184,12 +180,12 @@ class _Dynamic:
 
     def make_state(self, num_nodes: int, *, lift_steps: int = 2,
                    scan_method: str | None = None) -> DynamicCC:
-        return DynamicCC(num_nodes, lift_steps=lift_steps,
-                         scan_method=scan_method or "jnp")
+        """``lift_steps`` is accepted and has no effect: every engine
+        hooks flat labels."""
+        return DynamicCC(num_nodes, scan_method=scan_method or "jnp")
 
     def run(self, plan: ExecutionPlan) -> CCResult:
-        state = self.make_state(plan.num_nodes,
-                                lift_steps=plan.lift_steps)
+        state = self.make_state(plan.num_nodes)
         state.insert_graph(plan.graph)
         return CCResult(state.labels, WorkCounters(**state.work))
 
@@ -210,8 +206,7 @@ def _distributed(plan: ExecutionPlan) -> CCResult:
                          "(Solver.open(graph, mesh=...))")
     labels = dist_mod.solve_distributed(
         plan.graph, mesh,
-        axis_names=plan.opts.get("axis_names", ("data",)),
-        lift_steps=plan.lift_steps)
+        axis_names=plan.opts.get("axis_names", ("data",)))
     return CCResult(labels, WorkCounters.zeros())
 
 
@@ -269,7 +264,7 @@ def _sampled_specs():
         def fn(edges, true_edges):
             return sampled_mod._sample_phase_jit(
                 edges, true_edges, num_nodes=v, k=sampled_mod.SAMPLE_K,
-                sample_rounds=sampled_mod.SAMPLE_ROUNDS, lift_steps=2)
+                sample_rounds=sampled_mod.SAMPLE_ROUNDS)
         return (fn, (jax.ShapeDtypeStruct((e, 2), jnp.int32),
                      jax.ShapeDtypeStruct((), jnp.int32)),
                 [VarInfo(range=(0, v - 1), padded=True),
@@ -283,7 +278,7 @@ def _sampled_specs():
                 edges, true_edges, pi, rounds.empty_forest(v),
                 WorkCounters.zeros(), num_nodes=v,
                 num_segments=adaptive_num_segments(e, v),
-                lift_steps=2, fused=fused, interpret=True)
+                fused=fused, interpret=True)
         return (fn, (jax.ShapeDtypeStruct((e, 2), jnp.int32),
                      jax.ShapeDtypeStruct((), jnp.int32),
                      jax.ShapeDtypeStruct((v,), jnp.int32)),
@@ -356,7 +351,7 @@ def _batched_specs():
         def fn(edges, true_edges, true_nodes):
             return batch_mod._cc_batched_jit(
                 edges, true_edges, true_nodes, num_nodes=v,
-                num_segments=None, lift_steps=2)
+                num_segments=None)
         return (fn,
                 (jax.ShapeDtypeStruct((batch, per, 2), jnp.int32),
                  jax.ShapeDtypeStruct((batch,), jnp.int32),
@@ -377,7 +372,7 @@ def _incremental_specs():
 
         def fn(pi, new_edges, true_count, version):
             return inc_mod._absorb_jit(pi, new_edges, true_count,
-                                       version, lift_steps=2)
+                                       version)
         return (fn,
                 (jax.ShapeDtypeStruct((v,), jnp.int32),
                  jax.ShapeDtypeStruct((e, 2), jnp.int32),
@@ -401,7 +396,7 @@ def _delete_build(v: int, e: int, scan_method: str):
     def fn(edges, alive, pi, dels, d_true, version, deleted):
         return inc_mod._delete_jit(
             edges, alive, pi, dels, d_true, version, deleted,
-            lift_steps=2, num_segments=adaptive_num_segments(e, v),
+            num_segments=adaptive_num_segments(e, v),
             scan_method=scan_method, interpret=True)
     return (fn,
             (jax.ShapeDtypeStruct((e, 2), jnp.int32),
@@ -429,7 +424,7 @@ def _absorb_forest_build(v: int, e: int):
            version):
         return inc_mod._absorb_forest_jit(
             pi, parents, parent_eidx, new_edges, eid_base, true_count,
-            version, lift_steps=2)
+            version)
     return (fn,
             (jax.ShapeDtypeStruct((v,), jnp.int32),
              jax.ShapeDtypeStruct((v, 2), jnp.int32),
@@ -457,7 +452,7 @@ def _delete_forest_build(v: int, e: int):
            version, deleted, routes):
         return inc_mod._delete_forest_jit(
             edges, alive, pi, parents, parent_eidx, dels, d_true,
-            version, deleted, routes, lift_steps=2)
+            version, deleted, routes)
     return (fn,
             (jax.ShapeDtypeStruct((e, 2), jnp.int32),
              jax.ShapeDtypeStruct((e,), jnp.bool_),

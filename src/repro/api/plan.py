@@ -35,7 +35,7 @@ class ExecutionPlan:
     num_edges: int                 # true edges when statically known
     bucket: tuple                  # pow2 (V_pad, E_pad) — jit/autotune key
     segmentation: Optional[SegmentationPlan]
-    lift_steps: int = 2
+    lift_steps: int = 2            # accepted; no effect (flat-label hooks)
     num_segments: Optional[int] = None      # caller override (None = heuristic)
     graph: Any = dataclasses.field(default=None, repr=False)
     graphs: Any = dataclasses.field(default=None, repr=False)   # batched plans

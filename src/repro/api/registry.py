@@ -20,7 +20,7 @@ Adding a backend is a one-file, one-decorator change::
 
     @register_backend("my-engine", Capabilities(static=True))
     def _run(plan):
-        return my_engine(plan.graph, lift_steps=plan.lift_steps)
+        return my_engine(plan.graph, num_segments=plan.num_segments)
 
 The ``Solver`` facade and the adaptive policy then route to it by name;
 nothing else in the stack needs to know it exists.

@@ -121,7 +121,9 @@ class Solver:
             edge array (then ``num_nodes`` is required) — or ``None``
             for an empty streaming session over ``num_nodes`` vertices.
           num_nodes: |V| for raw arrays / empty sessions.
-          lift_steps: bounded root-chase depth (all engines).
+          lift_steps: accepted and has no effect. Every engine hooks
+            flat labels (a full compress runs before each hook), so a
+            root chase could never move a label and none is issued.
           num_segments: override the s = 2|E|/|V| heuristic.
           mesh: a ``jax.sharding.Mesh`` — plans default to the
             ``distributed`` backend over ``axis_names``.
@@ -333,8 +335,7 @@ class Solver:
         with obs.span("solver.spanning_forest", tenant=self.name,
                       method=method):
             res = cc_mod.solve_forest(self.graph(), method=method,
-                                      num_segments=self.num_segments,
-                                      lift_steps=self.lift_steps)
+                                      num_segments=self.num_segments)
         self._forest = (method, res, self.version)
         return res
 
@@ -344,7 +345,8 @@ class Solver:
                     lift_steps: int = 2) -> list[CCResult]:
         """Fleet solve through the ``batched`` backend: one device
         program per pow2 shape bucket, one ``CCResult`` per graph in
-        input order, bit-identical to per-graph solves."""
+        input order, bit-identical to per-graph solves. ``lift_steps``
+        has no effect (see ``open``)."""
         graphs = list(graphs)
         sizes = [(g.num_nodes, g.num_edges)
                  if hasattr(g, "num_nodes")
@@ -392,8 +394,7 @@ class Solver:
             # every later mutation jit follows it — no per-tick puts
             with self._device_scope():
                 self._dyn = get_backend("dynamic").make_state(
-                    self.num_nodes, lift_steps=self.lift_steps,
-                    scan_method=self._scan_method)
+                    self.num_nodes, scan_method=self._scan_method)
             if obs.enabled():
                 # span tracing on => carry the on-device Metrics pytree
                 # through every mutation jit (still transfer-free; host
@@ -618,7 +619,8 @@ def solve(graph, num_nodes: int | None = None, method: str = "auto", *,
           lift_steps: int = 2, mesh=None, axis_names=("data",),
           policy_cache: policy.AutotuneCache | None = None,
           **opts) -> CCResult:
-    """One-shot facade solve: ``Solver.open(...).solve(...)``."""
+    """One-shot facade solve: ``Solver.open(...).solve(...)``
+    (``lift_steps`` has no effect; see ``Solver.open``)."""
     return Solver.open(graph, num_nodes, lift_steps=lift_steps,
                        num_segments=num_segments, mesh=mesh,
                        axis_names=axis_names,

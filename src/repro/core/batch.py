@@ -73,9 +73,9 @@ def bucket_shape(num_nodes: int, num_edges: int) -> tuple[int, int]:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "num_segments", "lift_steps"))
+    jax.jit, static_argnames=("num_nodes", "num_segments"))
 def _cc_batched_jit(edges, true_edge_counts, true_node_counts, *,
-                    num_nodes, num_segments, lift_steps):
+                    num_nodes, num_segments):
     """vmapped adaptive CC over one bucket.
 
     edges: [B, E_pad, 2] int32 ((0,0)-padded);
@@ -84,7 +84,7 @@ def _cc_batched_jit(edges, true_edge_counts, true_node_counts, *,
     plan = plan_segmentation(edges.shape[1], num_nodes, num_segments)
 
     def one(ed, n_edges, n_nodes):
-        ops = rounds.jnp_round_ops(lift_steps, bill_nodes=n_nodes)
+        ops = rounds.jnp_round_ops(bill_nodes=n_nodes)
         pi, work = rounds.adaptive_rounds(ed, num_nodes, plan, ops=ops,
                                           true_edges=n_edges)
         return CCResult(pi, work.add(sync_rounds=1))
@@ -157,7 +157,6 @@ def bucketize(graphs: Sequence[tuple[np.ndarray, int]]
 def solve_batched(
     graphs: Sequence, *,
     num_segments: int | None = None,
-    lift_steps: int = 2,
 ) -> list[CCResult]:
     """Adaptive CC over a batch of graphs, one device program per shape
     bucket (engine entry for the facade's ``batched`` backend; callers
@@ -167,7 +166,6 @@ def solve_batched(
       graphs: sequence of ``repro.graphs.format.Graph`` objects or
         ``(edges [E,2], num_nodes)`` pairs; sizes may be mixed freely.
       num_segments: override the bucket's 2|E_pad|/|V_pad| heuristic.
-      lift_steps: bounded root-chase depth (as in the single-graph API).
 
     Returns:
       One ``CCResult`` per input graph, in input order, labels truncated
@@ -192,8 +190,7 @@ def solve_batched(
             jax.device_put(np.asarray(batch.true_edges)),
             jax.device_put(np.asarray(batch.true_nodes)),
             num_nodes=batch.num_nodes,
-            num_segments=num_segments,
-            lift_steps=lift_steps)
+            num_segments=num_segments)
         if device_in:
             # stay on device: per-row static slices, no transfers
             for row, i in enumerate(batch.indices):

@@ -9,10 +9,13 @@ TPU (see DESIGN.md §2 for the GPU→TPU mapping):
                       host-round-trip on the GPU baseline; we count them).
   * ``multijump``   — + the paper's Multi-Jump: the whole Compress phase is
                       fused into one on-device ``lax.while_loop``.
-  * ``atomic_hook`` — + the paper's Atomic-Hook: a root-chasing hook pass
-                      (bounded vectorized lift + deterministic scatter-min,
-                      the TPU analogue of the CAS chase) over the whole edge
+  * ``atomic_hook`` — + the paper's Atomic-Hook: a hook pass that writes
+                      roots only (deterministic scatter-min, the TPU
+                      analogue of the CAS chase) over the whole edge
                       list, fused with compress into a single device loop.
+                      The compress leaves every label at its root, so the
+                      hook reads roots with one gather per endpoint and
+                      needs no chase.
   * ``adaptive``    — + the paper's adaptive segmentation: the edge list is
                       split into s = 2|E|/|V| segments; each segment hook is
                       followed by a full compress (Fig. 4), all inside one
@@ -156,12 +159,12 @@ def _cc_multijump(edges: jnp.ndarray, num_nodes: int,
 # ---------------------------------------------------------------------------
 
 def _cc_atomic_hook(edges: jnp.ndarray, num_nodes: int,
-                    lift_steps: int = 2, true_edges=None) -> CCResult:
+                    true_edges=None) -> CCResult:
     # Atomic-Hook is the adaptive cleanup loop run from scratch over the
     # whole (single-segment) edge list.
     if true_edges is None:
         true_edges = edges.shape[0]
-    ops = rounds.jnp_round_ops(lift_steps)
+    ops = rounds.jnp_round_ops()
     pi0 = jnp.arange(num_nodes, dtype=jnp.int32)
     pi, work = rounds.cleanup_rounds(pi0, edges, ops, WorkCounters.zeros(),
                                      true_edges=true_edges)
@@ -175,15 +178,14 @@ def _cc_atomic_hook(edges: jnp.ndarray, num_nodes: int,
 # ---------------------------------------------------------------------------
 
 def _cc_adaptive(edges: jnp.ndarray, num_nodes: int,
-                 plan: SegmentationPlan, lift_steps: int = 2,
-                 true_edges=None) -> CCResult:
+                 plan: SegmentationPlan, true_edges=None) -> CCResult:
     """Fig. 4: for each of the s = 2|E|/|V| segments, Atomic-Hook the
     segment then fully compress, then a trailing consistency loop —
     all via the shared ``rounds.adaptive_rounds`` core, which bills
     hook_ops on true (unpadded) edges only.
     """
     pi, work = rounds.adaptive_rounds(
-        edges, num_nodes, plan, lift_steps=lift_steps,
+        edges, num_nodes, plan,
         true_edges=edges.shape[0] if true_edges is None else true_edges)
     work = work.add(sync_rounds=1)   # one jit call end-to-end
     return CCResult(pi, work)
@@ -194,20 +196,17 @@ def _cc_adaptive(edges: jnp.ndarray, num_nodes: int,
 # ---------------------------------------------------------------------------
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "method", "num_segments",
-                              "lift_steps"))
-def _cc_jit(edges, true_edges, *, num_nodes, method, num_segments,
-            lift_steps):
+    jax.jit, static_argnames=("num_nodes", "method", "num_segments"))
+def _cc_jit(edges, true_edges, *, num_nodes, method, num_segments):
     if method == "soman":
         return _cc_soman(edges, num_nodes, true_edges)
     if method == "multijump":
         return _cc_multijump(edges, num_nodes, true_edges)
     if method == "atomic_hook":
-        return _cc_atomic_hook(edges, num_nodes, lift_steps, true_edges)
+        return _cc_atomic_hook(edges, num_nodes, true_edges)
     if method == "adaptive":
         plan = plan_segmentation(edges.shape[0], num_nodes, num_segments)
-        return _cc_adaptive(edges, num_nodes, plan, lift_steps,
-                            true_edges)
+        return _cc_adaptive(edges, num_nodes, plan, true_edges)
     if method == "labelprop":
         from repro.core.labelprop import _cc_labelprop
         return _cc_labelprop(edges, num_nodes, true_edges)
@@ -216,17 +215,16 @@ def _cc_jit(edges, true_edges, *, num_nodes, method, num_segments,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "num_segments", "lift_steps",
-                              "interpret"))
+    jax.jit, static_argnames=("num_nodes", "num_segments", "interpret"))
 def _cc_fused_jit(edges, true_edges, *, num_nodes, num_segments,
-                  lift_steps, interpret):
+                  interpret):
     """method="pallas_fused": the shared adaptive composition over the
     fused segment-scan kernel — ONE pallas_call per segment scan (and
     one per cleanup round) instead of ``num_segments + jump_sweeps``
     launches. Labels and work counters are bit-compatible with the jnp
     backend (asserted in tests)."""
     plan = plan_segmentation(edges.shape[0], num_nodes, num_segments)
-    ops = rounds.fused_round_ops(lift_steps, interpret=interpret)
+    ops = rounds.fused_round_ops(interpret=interpret)
     pi, work = rounds.adaptive_rounds(edges, num_nodes, plan, ops=ops,
                                       true_edges=true_edges)
     return CCResult(pi, work.add(sync_rounds=1))
@@ -238,7 +236,6 @@ def solve_static(
     method: str = "adaptive",
     *,
     num_segments: int | None = None,
-    lift_steps: int = 2,
 ) -> CCResult:
     """Compute connected components (the engine entry the ``repro.api``
     backends dispatch to; callers should go through the facade —
@@ -260,7 +257,6 @@ def solve_static(
         fused segment-scan kernel (one launch per scan; interpret mode
         off-TPU).
       num_segments: override the adaptive 2|E|/|V| heuristic.
-      lift_steps: bounded root-chase depth in the Atomic-Hook analogue.
 
     Returns:
       ``CCResult(labels, work)`` with canonical min-id labels. Work is
@@ -281,7 +277,6 @@ def solve_static(
     if method in SAMPLED_METHODS:
         from repro.core.sampled import solve_sampled
         res = solve_sampled(g, num_segments=num_segments,
-                            lift_steps=lift_steps,
                             fused=(method == "sampled_fused"))
         return CCResult(res.labels, res.work)
     # the common exact-sized case keeps true_edges out of the traced
@@ -295,11 +290,9 @@ def solve_static(
         from repro.kernels import default_interpret
         return _cc_fused_jit(g.edges, true, num_nodes=g.num_nodes,
                              num_segments=g.plan.num_segments,
-                             lift_steps=lift_steps,
                              interpret=default_interpret())
     return _cc_jit(g.edges, true, num_nodes=g.num_nodes, method=method,
-                   num_segments=g.plan.num_segments,
-                   lift_steps=lift_steps)
+                   num_segments=g.plan.num_segments)
 
 
 # ---------------------------------------------------------------------------
@@ -307,10 +300,8 @@ def solve_static(
 # ---------------------------------------------------------------------------
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "method", "num_segments",
-                              "lift_steps"))
-def _cc_forest_jit(edges, true_edges, *, num_nodes, method, num_segments,
-                   lift_steps):
+    jax.jit, static_argnames=("num_nodes", "method", "num_segments"))
+def _cc_forest_jit(edges, true_edges, *, num_nodes, method, num_segments):
     """Forest-recording twin of ``_cc_jit``: identical pi updates and
     billing, with the parent-edge table threaded through every hook.
     Kept a SEPARATE program so the plain solves stay bit-identical."""
@@ -328,8 +319,7 @@ def _cc_forest_jit(edges, true_edges, *, num_nodes, method, num_segments,
 
         def outer_body(state):
             pi, f, _, rounds_, w = state
-            new_pi, f = rounds.hook_edges_forest(pi, f, edges,
-                                                 lift_steps=0)
+            new_pi, f = rounds.hook_edges_forest(pi, f, edges)
             hook_changed = jnp.any(new_pi != pi)
             w = w.add(hook_ops=e, hook_rounds=1, sync_rounds=per_round)
             new_pi, w = compress(new_pi, w, count_syncs=count_syncs)
@@ -343,14 +333,13 @@ def _cc_forest_jit(edges, true_edges, *, num_nodes, method, num_segments,
 
     if method == "atomic_hook":
         pi, parents, work = rounds.forest_cleanup_rounds(
-            pi0, parents0, edges, WorkCounters.zeros(),
-            true_edges=e, lift_steps=lift_steps)
+            pi0, parents0, edges, WorkCounters.zeros(), true_edges=e)
         return ForestResult(pi, parents, work.add(sync_rounds=1))
 
     if method == "adaptive":
         plan = plan_segmentation(edges.shape[0], num_nodes, num_segments)
         pi, parents, work = rounds.forest_adaptive_rounds(
-            edges, num_nodes, plan, lift_steps=lift_steps, true_edges=e)
+            edges, num_nodes, plan, true_edges=e)
         return ForestResult(pi, parents, work.add(sync_rounds=1))
 
     raise ValueError(f"unknown forest method {method!r}; choose from "
@@ -363,7 +352,6 @@ def solve_forest(
     method: str = "adaptive",
     *,
     num_segments: int | None = None,
-    lift_steps: int = 2,
 ) -> ForestResult:
     """Connected components WITH the spanning forest: the parent edges
     each hook round records, as a first-class product (DESIGN.md §13).
@@ -389,16 +377,14 @@ def solve_forest(
                             WorkCounters.zeros())
     if method == "sampled":
         from repro.core.sampled import solve_sampled
-        res = solve_sampled(g, num_segments=num_segments,
-                            lift_steps=lift_steps, fused=False)
+        res = solve_sampled(g, num_segments=num_segments, fused=False)
         return ForestResult(res.labels, res.parents, res.work)
     t = g.true_edges_static
     true = None if (t is not None and t == int(g.edges.shape[0])) \
         else g.true_edges_device()
     return _cc_forest_jit(g.edges, true, num_nodes=g.num_nodes,
                           method=method,
-                          num_segments=g.plan.num_segments,
-                          lift_steps=lift_steps)
+                          num_segments=g.plan.num_segments)
 
 
 def connected_components(
@@ -425,13 +411,10 @@ def connected_components(
 # ---------------------------------------------------------------------------
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "num_segments", "lift_steps",
-                              "interpret"))
-def _cc_adaptive_pallas(edges, *, num_nodes, num_segments, lift_steps,
-                        interpret):
+    jax.jit, static_argnames=("num_nodes", "num_segments", "interpret"))
+def _cc_adaptive_pallas(edges, *, num_nodes, num_segments, interpret):
     plan = plan_segmentation(edges.shape[0], num_nodes, num_segments)
     ops = rounds.pallas_round_ops(
-        lift_steps=lift_steps,
         edge_tile=min(1024, plan.segment_size),
         node_tile=min(512, max(8, num_nodes)),
         interpret=interpret)
@@ -442,7 +425,6 @@ def _cc_adaptive_pallas(edges, *, num_nodes, num_segments, lift_steps,
 
 def solve_pallas(graph, num_nodes: int | None = None, *,
                  num_segments: int | None = None,
-                 lift_steps: int = 2,
                  interpret: bool | None = None) -> jnp.ndarray:
     """Adaptive CC on the per-round Pallas kernel backend (hook +
     multi_jump kernels; DESIGN.md §2) — one launch per segment hook and
@@ -459,7 +441,7 @@ def solve_pallas(graph, num_nodes: int | None = None, *,
         return jnp.arange(g.num_nodes, dtype=jnp.int32)
     return _cc_adaptive_pallas(g.edges, num_nodes=g.num_nodes,
                                num_segments=g.plan.num_segments,
-                               lift_steps=lift_steps, interpret=interpret)
+                               interpret=interpret)
 
 
 def connected_components_pallas(graph, num_nodes: int | None = None, *,

@@ -5,8 +5,10 @@ Spatial reinterpretation of the paper's segmentation (DESIGN.md §5):
   * edges are sharded over the mesh's data-parallel axes — each chip owns
     an edge partition (a "segment" in the paper's vocabulary);
   * the parent array π (the |V| workspace) is replicated;
-  * each round every chip hooks its own segment (scatter-min, bounded
-    lift), the per-chip π copies are merged with an elementwise
+  * each round every chip hooks its own segment (scatter-min over flat
+    labels: π is identity or compressed at each round's start, and the
+    segment scan compresses after every segment hook), the per-chip π
+    copies are merged with an elementwise
     ``pmin`` all-reduce — valid because scatter-min updates are monotone
     decreasing, so the elementwise min of per-chip results equals the
     result of hooking the union of the segments — then every chip runs the
@@ -45,7 +47,6 @@ _MAX_ROUNDS = 8
 
 def build_distributed_cc(graph, mesh: Mesh,
                          axis_names: tuple[str, ...] = ("data",),
-                         lift_steps: int = 2,
                          local_segments: int | None = None):
     """Build a jitted distributed-CC callable for a sharded DeviceGraph
     (engine entry for the facade's ``distributed`` backend; callers
@@ -81,7 +82,7 @@ def build_distributed_cc(graph, mesh: Mesh,
     # scan silently truncated the remainder when edges_per_shard wasn't
     # divisible by the segment count).
     plan = plan_segmentation(edges_per_shard, num_nodes, segs)
-    ops = rounds_lib.jnp_round_ops(lift_steps)
+    ops = rounds_lib.jnp_round_ops()
 
     def shard_fn(edges_local):
         # edges_local: [1 per sharded axis..., edges_per_shard, 2]
@@ -145,11 +146,9 @@ class DistributedRunnerCache:
     of one per tick. Host-side dict only; hit/miss counters ride in
     ``stats`` for the fleet benchmark."""
 
-    def __init__(self, mesh: Mesh, axis_names=("data",),
-                 lift_steps: int = 2):
+    def __init__(self, mesh: Mesh, axis_names=("data",)):
         self.mesh = mesh
         self.axis_names = tuple(axis_names)
-        self.lift_steps = lift_steps
         self._runners: dict = {}
         self.stats = {"hits": 0, "misses": 0}
 
@@ -161,8 +160,7 @@ class DistributedRunnerCache:
         if fn is None:
             self.stats["misses"] += 1
             fn = self._runners[key] = build_distributed_cc(
-                graph, self.mesh, axis_names=self.axis_names,
-                lift_steps=self.lift_steps)
+                graph, self.mesh, axis_names=self.axis_names)
         else:
             self.stats["hits"] += 1
         return fn
@@ -176,15 +174,13 @@ class DistributedRunnerCache:
         return self.run(graph.shard(self.mesh, self.axis_names))
 
 
-def solve_distributed(graph, mesh: Mesh, axis_names=("data",),
-                      lift_steps: int = 2):
+def solve_distributed(graph, mesh: Mesh, axis_names=("data",)):
     """Shard a graph (host ``Graph``, raw arrays, or an unsharded
     ``DeviceGraph``) over ``mesh`` and run (engine entry for the
     facade's ``distributed`` backend)."""
     from repro.graphs.device import as_device_graph
     dg = as_device_graph(graph).shard(mesh, axis_names)
-    fn = build_distributed_cc(dg, mesh, axis_names=axis_names,
-                              lift_steps=lift_steps)
+    fn = build_distributed_cc(dg, mesh, axis_names=axis_names)
     return fn(dg)
 
 
@@ -193,12 +189,13 @@ def make_distributed_cc(graph, mesh: Mesh,
                         lift_steps: int = 2,
                         local_segments: int | None = None):
     """DEPRECATED legacy entrypoint — forwards to the engine builder
-    the facade's ``distributed`` backend uses."""
+    the facade's ``distributed`` backend uses. ``lift_steps`` is
+    accepted and has no effect: every engine hooks flat labels."""
     from repro._deprecation import warn_once
     warn_once("repro.core.distributed.make_distributed_cc",
               "repro.api.Solver.open(graph, mesh=mesh)")
+    del lift_steps
     return build_distributed_cc(graph, mesh, axis_names=axis_names,
-                                lift_steps=lift_steps,
                                 local_segments=local_segments)
 
 

@@ -52,14 +52,16 @@ _MIN_BATCH_PAD = 64
 _DRAIN_EVERY = 256   # fold pending per-batch work into host ints
 
 
-@functools.partial(jax.jit, static_argnames=("lift_steps",))
-def _absorb_jit(pi, new_edges, true_count, version, *, lift_steps):
+@jax.jit
+def _absorb_jit(pi, new_edges, true_count, version):
     """One absorb: cleanup loop over the new edges + merge detection +
-    version tick, all in ONE device program. Returns the PER-BATCH
-    work counters (int32 — safe for a single batch; the caller
-    accumulates across batches in host arbitrary-precision ints,
-    lazily, so no int32 wraparound over a long-lived instance)."""
-    ops = rounds.jnp_round_ops(lift_steps)
+    version tick, all in ONE device program. ``pi`` is the state's
+    canonical labels, which are flat, so the loop hooks without a root
+    chase. Returns the PER-BATCH work counters (int32 — safe for a
+    single batch; the caller accumulates across batches in host
+    arbitrary-precision ints, lazily, so no int32 wraparound over a
+    long-lived instance)."""
+    ops = rounds.jnp_round_ops()
     new_pi, work = rounds.cleanup_rounds(pi, new_edges, ops,
                                          WorkCounters.zeros(),
                                          true_edges=true_count)
@@ -88,11 +90,10 @@ class IncrementalCC:
     0
     """
 
-    def __init__(self, num_nodes: int, *, lift_steps: int = 2):
+    def __init__(self, num_nodes: int):
         if num_nodes < 0:
             raise ValueError(f"num_nodes must be >= 0, got {num_nodes}")
         self.num_nodes = num_nodes
-        self.lift_steps = lift_steps
         self._pi = jnp.arange(num_nodes, dtype=jnp.int32)
         self.num_edges_inserted = 0
         self.batches_absorbed = 0
@@ -194,8 +195,7 @@ class IncrementalCC:
         padded[:e] = new_edges
         v0, true_count = self._version, jax.device_put(np.int32(e))
         self._pi, self._version, batch_work = _absorb_jit(
-            self._pi, jax.device_put(padded), true_count, self._version,
-            lift_steps=self.lift_steps)
+            self._pi, jax.device_put(padded), true_count, self._version)
         self._queue_work(batch_work)
         self._record_metrics("insert", batch_work, true_count, v0)
         return self._pi
@@ -218,8 +218,7 @@ class IncrementalCC:
         padded = delta.pad_pow2(min_rows=_MIN_BATCH_PAD)
         v0, true_count = self._version, padded.true_edges_device()
         self._pi, self._version, batch_work = _absorb_jit(
-            self._pi, padded.edges, true_count,
-            self._version, lift_steps=self.lift_steps)
+            self._pi, padded.edges, true_count, self._version)
         self._queue_work(batch_work)
         self._record_metrics("insert", batch_work, true_count, v0)
         return self._pi
@@ -268,10 +267,10 @@ class IncrementalCC:
 # Fully-dynamic connectivity: + edge deletions (DESIGN.md §9)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("lift_steps", "num_segments",
-                                             "scan_method", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "scan_method",
+                                             "interpret"))
 def _delete_jit(edges, alive, pi, dels, d_true, version, deleted, *,
-                lift_steps, num_segments, scan_method, interpret):
+                num_segments, scan_method, interpret):
     """One delete tick, ONE device program: tombstone the delete batch
     against the log, detect the affected components, and — only if the
     batch actually retired an edge — run the scoped recompute over
@@ -298,11 +297,10 @@ def _delete_jit(edges, alive, pi, dels, d_true, version, deleted, *,
         edge_aff = alive2 & in_aff[edges[:, 0]]
         n_aff_nodes = jnp.sum(in_aff).astype(jnp.int32)
         if scan_method == "pallas_fused":
-            ops = rounds.fused_round_ops(lift_steps, interpret=interpret,
+            ops = rounds.fused_round_ops(interpret=interpret,
                                          bill_nodes=n_aff_nodes)
         else:
-            ops = rounds.jnp_round_ops(lift_steps,
-                                       bill_nodes=n_aff_nodes)
+            ops = rounds.jnp_round_ops(bill_nodes=n_aff_nodes)
         return rounds.scoped_rounds(pi, edges, edge_aff, in_aff, plan,
                                     ops, WorkCounters.zeros())
 
@@ -322,9 +320,9 @@ def _delete_jit(edges, alive, pi, dels, d_true, version, deleted, *,
 # Maintained spanning forest (DESIGN.md §14): forest-threading jits
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("lift_steps",))
+@jax.jit
 def _absorb_forest_jit(pi, parents, parent_eidx, new_edges, eid_base,
-                       true_count, version, *, lift_steps):
+                       true_count, version):
     """``_absorb_jit`` + forest extension: the batch's true rows were
     just appended to the EdgeLog at offset ``eid_base`` (a TRACED
     device scalar — a static offset would recompile once per append
@@ -338,15 +336,15 @@ def _absorb_forest_jit(pi, parents, parent_eidx, new_edges, eid_base,
     eids = jnp.where(slot < true_count, eid_base + slot, -1)
     new_pi, parents, parent_eidx, work = rounds.forest_cleanup_rounds_ids(
         pi, parents, parent_eidx, new_edges, eids, WorkCounters.zeros(),
-        true_edges=true_count, lift_steps=lift_steps)
+        true_edges=true_count)
     work = work.add(sync_rounds=1)
     version = version + jnp.any(new_pi != pi).astype(version.dtype)
     return new_pi, parents, parent_eidx, version, work
 
 
-@functools.partial(jax.jit, static_argnames=("lift_steps",))
+@jax.jit
 def _delete_forest_jit(edges, alive, pi, parents, parent_eidx, dels,
-                       d_true, version, deleted, routes, *, lift_steps):
+                       d_true, version, deleted, routes):
     """The tree-aware delete tick, ONE device program (DESIGN.md §14):
 
     1. tombstone the batch (orientation-blind multiset matching via
@@ -359,9 +357,7 @@ def _delete_forest_jit(edges, alive, pi, parents, parent_eidx, dels,
        UNTOUCHED (the common case bills zero hook rounds and zero
        sweeps); otherwise ``rounds.forest_scoped_rounds`` reconnects
        only the components that lost a tree edge via the surviving
-       forest skeleton + crossing replacement edges (unlifted hooks —
-       ``lift_steps`` only keeps this tick's static signature parallel
-       to the absorb jit's).
+       forest skeleton + crossing replacement edges.
 
     ``routes`` is an int32 [2] device counter
     (nontree_shortcircuit, tree_scoped) — how a batch classified is
@@ -399,10 +395,8 @@ def _delete_forest_jit(edges, alive, pi, parents, parent_eidx, dels,
     return pi1, alive2, parents1, eidx1, version, deleted, routes, work
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "lift_steps",
-                                             "num_segments"))
-def _rebuild_forest_jit(edges, alive, *, num_nodes, lift_steps,
-                        num_segments):
+@functools.partial(jax.jit, static_argnames=("num_nodes", "num_segments"))
+def _rebuild_forest_jit(edges, alive, *, num_nodes, num_segments):
     """From-scratch forest (re)derivation over the surviving log — the
     lazy fallback when a bulk route (static rebuild, tombstone-only
     delete) left the maintained forest stale. Runs the Fig. 4 pipeline
@@ -425,10 +419,9 @@ def _rebuild_forest_jit(edges, alive, *, num_nodes, lift_steps,
     pi, parents, eidx, work = rounds.forest_segment_scan_ids(
         pi0, rounds.empty_forest(num_nodes),
         rounds.empty_forest_idx(num_nodes), segments, seg_ids,
-        WorkCounters.zeros(), counts, lift_steps=lift_steps)
+        WorkCounters.zeros(), counts)
     pi, parents, eidx, work = rounds.forest_cleanup_rounds_ids(
-        pi, parents, eidx, packed, pids, work, true_edges=true,
-        lift_steps=lift_steps)
+        pi, parents, eidx, packed, pids, work, true_edges=true)
     work = work.add(sync_rounds=1)
     return pi, parents, eidx, work
 
@@ -475,9 +468,8 @@ class DynamicCC(IncrementalCC):
     (True, False)
     """
 
-    def __init__(self, num_nodes: int, *, lift_steps: int = 2,
-                 scan_method: str = "jnp"):
-        super().__init__(num_nodes, lift_steps=lift_steps)
+    def __init__(self, num_nodes: int, *, scan_method: str = "jnp"):
+        super().__init__(num_nodes)
         from repro.graphs.device import EdgeLog
         if scan_method not in ("jnp", "pallas_fused"):
             raise ValueError(f"unknown scan_method {scan_method!r}; "
@@ -540,7 +532,7 @@ class DynamicCC(IncrementalCC):
          batch_work) = _absorb_forest_jit(
             self._pi, self._parents, self._parent_eidx, padded.edges,
             jax.device_put(np.int32(rows_before)), true_count,
-            self._version, lift_steps=self.lift_steps)
+            self._version)
         self._queue_work(batch_work)
         self._record_metrics("insert", batch_work, true_count, v0)
         return self._pi
@@ -592,7 +584,6 @@ class DynamicCC(IncrementalCC):
          batch_work) = _delete_jit(
             self.log.edges, self.log.alive, self._pi, padded.edges,
             true_count, self._version, self._deleted,
-            lift_steps=self.lift_steps,
             num_segments=adaptive_num_segments(self.log.capacity,
                                                self.num_nodes),
             scan_method=self.scan_method,
@@ -618,7 +609,6 @@ class DynamicCC(IncrementalCC):
         (self._pi, self._parents, self._parent_eidx,
          work) = _rebuild_forest_jit(
             self.log.edges, self.log.alive, num_nodes=self.num_nodes,
-            lift_steps=self.lift_steps,
             num_segments=adaptive_num_segments(self.log.capacity,
                                                self.num_nodes))
         self._queue_work(work)
@@ -651,8 +641,7 @@ class DynamicCC(IncrementalCC):
          batch_work) = _delete_forest_jit(
             self.log.edges, self.log.alive, self._pi, self._parents,
             self._parent_eidx, padded.edges, true_count, self._version,
-            self._deleted, self._delete_routes,
-            lift_steps=self.lift_steps)
+            self._deleted, self._delete_routes)
         self._queue_work(batch_work)
         self._record_metrics("delete", batch_work, true_count, v0)
         return self._pi

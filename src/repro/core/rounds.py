@@ -1,7 +1,7 @@
 """Shared adaptive hook+compress round machinery (DESIGN.md §3).
 
 This module is the single home of the paper's round primitives —
-deterministic Hook (scatter-min with bounded root chase), fused
+deterministic Hook (scatter-min over flat labels), fused
 Multi-Jump Compress (pointer doubling in one ``lax.while_loop``), the
 work counters, and the segment-scan / cleanup-loop composition of Fig. 4
 — so that every execution mode consumes ONE implementation:
@@ -15,6 +15,13 @@ work counters, and the segment-scan / cleanup-loop composition of Fig. 4
 
 Everything here is pure jnp + lax control flow: safe under ``vmap``
 (batched CC), ``shard_map`` (distributed CC), and jit caching.
+
+Every composition here hooks FLAT labels: callers hand in a flat pi
+(identity, canonical labels, or a scoped reset of them) and a full
+compress follows every hook, so ``pi[pi[x]] == pi[x]`` whenever a hook
+reads pi. A hook therefore gathers each
+endpoint's label once and issues no root chase: the chase's re-gathers
+could never change a value.
 
 Work accounting (the paper's currency is work-efficiency) bills *true*
 edge counts: padded ``(0, 0)`` no-op edges — introduced by segmentation,
@@ -61,7 +68,9 @@ def compress_fuel(num_nodes: int) -> int:
 class WorkCounters(NamedTuple):
     """Hardware-independent work counters (DESIGN.md §2).
 
-    * ``hook_ops``    — edge-hook evaluations performed (true edges only),
+    * ``hook_ops``    — edge-hook evaluations performed (true edges only;
+                        one per edge per hook round: the compositions
+                        hook flat labels and issue no root chase),
     * ``jump_ops``    — vertex-jump (gather) evaluations performed,
     * ``jump_sweeps`` — full |V|-wide pointer-jump sweeps,
     * ``hook_rounds`` — edge-set hook rounds,
@@ -100,7 +109,9 @@ def hook_edges(pi: jnp.ndarray, edges: jnp.ndarray, lift_steps: int = 0
     ``pi[H] <- min(pi[H], L)`` via scatter-min (race-free winner selection —
     the deterministic stand-in for the CAS consensus; identical fixed point
     under the paper's high-to-low rule). ``lift_steps`` performs the bounded
-    vectorized root chase of Atomic-Hook (pu <- pi[pu]) before hooking.
+    vectorized root chase of Atomic-Hook (pu <- pi[pu]) before hooking;
+    the compositions in this module hook flat labels and pass 0. The
+    chase is for callers whose labels are not flat.
     """
     u, v = edges[..., 0], edges[..., 1]
     pu, pv = pi[u], pi[v]
@@ -164,11 +175,9 @@ def edges_consistent(pi: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
 class RoundOps(NamedTuple):
     """The pluggable kernels of a hook+compress round.
 
-    * ``hook(pi, edges) -> pi``        — one hook pass over an edge set,
+    * ``hook(pi, edges) -> pi``        — one hook pass over an edge set
+                                         (billed 1 per true edge),
     * ``compress(pi, work) -> (pi, work)`` — full compress, threading work,
-    * ``bill_lift``                    — hook evaluations billed per true
-                                         edge (1 + lift_steps for the
-                                         root-chasing Atomic-Hook),
     * ``scan``                         — optional FUSED segment scan:
       ``scan(pi, segments, true_counts, work) -> (pi, work)`` runs the
       whole Fig. 4 inner pipeline (every hook round + every compress
@@ -180,67 +189,65 @@ class RoundOps(NamedTuple):
     hook: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
     compress: Callable[[jnp.ndarray, WorkCounters],
                        tuple[jnp.ndarray, WorkCounters]]
-    bill_lift: int
     scan: Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray, WorkCounters],
                    tuple[jnp.ndarray, WorkCounters]] | None = None
 
 
-def jnp_round_ops(lift_steps: int = 2,
-                  bill_nodes: int | jnp.ndarray | None = None) -> RoundOps:
-    """Pure-jnp ops (the default backend)."""
+def jnp_round_ops(bill_nodes: int | jnp.ndarray | None = None
+                  ) -> RoundOps:
+    """Pure-jnp ops (the default backend): flat-label hook + compress."""
     return RoundOps(
-        hook=lambda pi, e: hook_edges(pi, e, lift_steps=lift_steps),
+        hook=hook_edges,
         compress=lambda pi, w: compress(pi, w, bill_nodes=bill_nodes),
-        bill_lift=1 + lift_steps,
     )
 
 
-def pallas_round_ops(lift_steps: int, edge_tile: int, node_tile: int,
+def pallas_round_ops(edge_tile: int, node_tile: int,
                      interpret: bool) -> RoundOps:
     """Pallas-kernel-backed ops (hook + multi_jump kernels, DESIGN.md §2).
     The kernels do not thread work counters; compress passes them through.
+    The hook kernel walks its edge tiles in order against one pi, so a
+    later tile can read a root an earlier tile just hooked: it keeps its
+    bounded root chase (the kernel's default depth), which can still
+    move a label there.
     """
     from repro.kernels.hook.ops import hook_edges_pallas
     from repro.kernels.multi_jump.ops import full_compress
     return RoundOps(
         hook=lambda pi, e: hook_edges_pallas(
-            pi, e, edge_tile=edge_tile, lift_steps=lift_steps,
-            interpret=interpret),
+            pi, e, edge_tile=edge_tile, interpret=interpret),
         compress=lambda pi, w: (full_compress(
             pi, tile=node_tile, interpret=interpret), w),
-        bill_lift=1 + lift_steps,
     )
 
 
-def fused_round_ops(lift_steps: int = 2, *,
-                    interpret: bool | None = None,
+def fused_round_ops(*, interpret: bool | None = None,
                     bill_nodes: int | jnp.ndarray | None = None
                     ) -> RoundOps:
     """Fused-kernel ops (``kernels.cc_fused``): the whole segment scan —
-    hook rounds with bounded root chase plus multi-jump compress — in
-    ONE ``pallas_call`` per scan. Billing is bit-compatible with the
-    jnp backend: hook_ops on scalar-prefetched TRUE per-segment counts,
+    flat-label hook rounds plus multi-jump compress — in ONE
+    ``pallas_call`` per scan. The kernel compresses every segment, so
+    it runs at chase depth 0. Billing is bit-compatible with the jnp
+    backend: hook_ops on scalar-prefetched TRUE per-segment counts,
     jump_sweeps from the kernel's exact per-segment sweep counters.
     ``hook``/``compress`` fall back to the jnp primitives (used only by
     callers that bypass the fused scan)."""
     from repro.kernels.cc_fused.ops import fused_segment_scan
-    bill = 1 + lift_steps
 
     def scan(pi, segments, true_counts, work):
         v = pi.shape[0] if bill_nodes is None else bill_nodes
         pi, sweeps = fused_segment_scan(pi, segments, true_counts,
-                                        lift_steps=lift_steps,
+                                        lift_steps=0,
                                         interpret=interpret)
         total = jnp.sum(sweeps)
         return pi, work.add(
-            hook_ops=jnp.sum(true_counts) * bill,
+            hook_ops=jnp.sum(true_counts),
             hook_rounds=segments.shape[0],
             jump_ops=total * v, jump_sweeps=total)
 
     return RoundOps(
-        hook=lambda pi, e: hook_edges(pi, e, lift_steps=lift_steps),
+        hook=hook_edges,
         compress=lambda pi, w: compress(pi, w, bill_nodes=bill_nodes),
-        bill_lift=bill,
         scan=scan,
     )
 
@@ -249,15 +256,24 @@ def fused_round_ops(lift_steps: int = 2, *,
 # Forest-recording hook (spanning forest as a by-product of hook rounds)
 # ---------------------------------------------------------------------------
 # Every hook round runs over a fully-compressed pi (all compositions in
-# this module compress to fixpoint between hooks), so a scatter-min
-# write at position ``hi`` is a STRICT decrease of a root's own label:
-# pi[hi] == hi before the write, pi[hi] = lo < hi after, and hi never
-# reappears as a label. Each position is therefore recorded at most
-# once over the whole run, each recorded edge merges two components
-# that were distinct at record time, and the recorded set is exactly a
-# spanning forest of the input: V - C edges, one unrecorded root (the
-# component minimum) per component. These are SEPARATE compositions
-# from the plain ones above so the non-forest paths stay bit-identical.
+# this module compress to fixpoint between hooks), so ``hi`` is a root
+# and a scatter-min write at position ``hi`` is a STRICT decrease of
+# its own label: pi[hi] == hi before the write, pi[hi] = lo < hi
+# after, and hi never reappears as a label. Each position is therefore
+# recorded at most once over the whole run, each recorded edge merges
+# two components that were distinct at record time, and the recorded
+# set is exactly a spanning forest of the input: V - C edges, one
+# unrecorded root (the component minimum) per component. These are
+# SEPARATE compositions from the plain ones above so the non-forest
+# paths stay bit-identical.
+#
+# The win test compares against the pre-hook ``pi[hi]``, which equals
+# ``hi`` on a flat pi, and keeps that gather on purpose: on a TPU v5e
+# it is what leads XLA's memory-space assignment to keep a 2^22-vertex
+# pi in on-chip VMEM across the segment scan, where every compress
+# sweep gathers it. Testing ``new_pi[hi] < hi`` instead saves the
+# gather but leaves pi in HBM, and the sampled residue scan's compress
+# sweeps then take 3.3x as long (soc-live-journal stand-in).
 
 
 def empty_forest(num_nodes: int) -> jnp.ndarray:
@@ -270,9 +286,10 @@ def empty_forest(num_nodes: int) -> jnp.ndarray:
 
 @_scope("hook")
 def hook_edges_forest(pi: jnp.ndarray, parents: jnp.ndarray,
-                      edges: jnp.ndarray, lift_steps: int = 0
+                      edges: jnp.ndarray
                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``hook_edges`` + spanning-forest recording (same pi updates).
+    ``pi`` must be flat, as in every composition of this module.
 
     An edge wins position ``hi`` iff its scatter-min write actually
     landed (``new_pi[hi] == lo``) AND strictly lowered the root's label
@@ -284,12 +301,11 @@ def hook_edges_forest(pi: jnp.ndarray, parents: jnp.ndarray,
     n = pi.shape[0]
     u, v = edges[..., 0], edges[..., 1]
     pu, pv = pi[u], pi[v]
-    for _ in range(lift_steps):
-        pu, pv = pi[pu], pi[pv]
     hi = jnp.maximum(pu, pv)
     lo = jnp.minimum(pu, pv)
     new_pi = pi.at[hi].min(lo)
-    won = jnp.logical_and(new_pi[hi] == lo, new_pi[hi] < pi[hi])
+    landed = new_pi[hi]
+    won = jnp.logical_and(landed == lo, landed < pi[hi])
     eidx = jnp.arange(edges.shape[0], dtype=jnp.int32)
     sentinel = jnp.full((n,), jnp.iinfo(jnp.int32).max, jnp.int32)
     winner = sentinel.at[jnp.where(won, hi, n)].min(eidx, mode="drop")
@@ -303,17 +319,14 @@ def hook_edges_forest(pi: jnp.ndarray, parents: jnp.ndarray,
 def forest_segment_scan(pi: jnp.ndarray, parents: jnp.ndarray,
                         segments: jnp.ndarray, work: WorkCounters,
                         true_counts: jnp.ndarray,
-                        lift_steps: int = 2,
                         ) -> tuple[jnp.ndarray, jnp.ndarray, WorkCounters]:
     """``segment_scan`` with the parent-edge table threaded through the
     ``lax.scan`` carry (jnp ops only; billing matches ``jnp_round_ops``)."""
-    bill = 1 + lift_steps
-
     def seg_body(carry, xs):
         p, f, w = carry
         seg, cnt = xs
-        p, f = hook_edges_forest(p, f, seg, lift_steps=lift_steps)
-        w = w.add(hook_ops=cnt * bill, hook_rounds=1)
+        p, f = hook_edges_forest(p, f, seg)
+        w = w.add(hook_ops=cnt, hook_rounds=1)
         p, w = compress(p, w)
         return (p, f, w), None
 
@@ -326,14 +339,13 @@ def forest_segment_scan(pi: jnp.ndarray, parents: jnp.ndarray,
 def forest_cleanup_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
                           edges: jnp.ndarray, work: WorkCounters,
                           true_edges: int | jnp.ndarray | None = None,
-                          lift_steps: int = 2,
                           max_rounds: int = MAX_ROUNDS,
                           ) -> tuple[jnp.ndarray, jnp.ndarray, WorkCounters]:
     """``cleanup_rounds`` with forest recording (same short-circuit on
     already-consistent edge sets, same true-edge billing)."""
     if true_edges is None:
         true_edges = edges.shape[0]
-    bill = jnp.asarray(true_edges, jnp.int32) * (1 + lift_steps)
+    bill = jnp.asarray(true_edges, jnp.int32)
 
     def cond(state):
         _, _, done, rounds_, _ = state
@@ -341,7 +353,7 @@ def forest_cleanup_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
 
     def body(state):
         p, f, _, rounds_, w = state
-        p, f = hook_edges_forest(p, f, edges, lift_steps=lift_steps)
+        p, f = hook_edges_forest(p, f, edges)
         w = w.add(hook_ops=bill, hook_rounds=1)
         p, w = compress(p, w)
         return p, f, edges_consistent(p, edges), rounds_ + 1, w
@@ -355,7 +367,6 @@ def forest_cleanup_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
 
 def forest_adaptive_rounds(edges: jnp.ndarray, num_nodes: int,
                            plan: SegmentationPlan, *,
-                           lift_steps: int = 2,
                            true_edges: int | jnp.ndarray | None = None,
                            max_rounds: int = MAX_ROUNDS,
                            ) -> tuple[jnp.ndarray, jnp.ndarray,
@@ -370,11 +381,11 @@ def forest_adaptive_rounds(edges: jnp.ndarray, num_nodes: int,
     pi0 = jnp.arange(num_nodes, dtype=jnp.int32)
     pi, parents, work = forest_segment_scan(
         pi0, empty_forest(num_nodes), segments, WorkCounters.zeros(),
-        counts, lift_steps=lift_steps)
+        counts)
     flat = segments.reshape(-1, 2)
     pi, parents, work = forest_cleanup_rounds(
         pi, parents, flat, work, true_edges=true_edges,
-        lift_steps=lift_steps, max_rounds=max_rounds)
+        max_rounds=max_rounds)
     return pi, parents, work
 
 
@@ -397,7 +408,7 @@ def empty_forest_idx(num_nodes: int) -> jnp.ndarray:
 @_scope("hook")
 def hook_edges_forest_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                           parent_eidx: jnp.ndarray, edges: jnp.ndarray,
-                          edge_ids: jnp.ndarray, lift_steps: int = 0,
+                          edge_ids: jnp.ndarray,
                           ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``hook_edges_forest`` + external-id recording (same pi updates,
     same tie-break: the lowest batch SLOT wins, and that slot's
@@ -407,12 +418,11 @@ def hook_edges_forest_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     n = pi.shape[0]
     u, v = edges[..., 0], edges[..., 1]
     pu, pv = pi[u], pi[v]
-    for _ in range(lift_steps):
-        pu, pv = pi[pu], pi[pv]
     hi = jnp.maximum(pu, pv)
     lo = jnp.minimum(pu, pv)
     new_pi = pi.at[hi].min(lo)
-    won = jnp.logical_and(new_pi[hi] == lo, new_pi[hi] < pi[hi])
+    landed = new_pi[hi]
+    won = jnp.logical_and(landed == lo, landed < pi[hi])
     slot = jnp.arange(edges.shape[0], dtype=jnp.int32)
     sentinel = jnp.full((n,), jnp.iinfo(jnp.int32).max, jnp.int32)
     winner = sentinel.at[jnp.where(won, hi, n)].min(slot, mode="drop")
@@ -429,7 +439,6 @@ def forest_cleanup_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                               edges: jnp.ndarray, edge_ids: jnp.ndarray,
                               work: WorkCounters,
                               true_edges: int | jnp.ndarray | None = None,
-                              lift_steps: int = 2,
                               max_rounds: int = MAX_ROUNDS,
                               bill_nodes: int | jnp.ndarray | None = None,
                               ) -> tuple[jnp.ndarray, jnp.ndarray,
@@ -439,7 +448,7 @@ def forest_cleanup_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     count) so compress sweeps bill the scoped region, not |V|."""
     if true_edges is None:
         true_edges = edges.shape[0]
-    bill = jnp.asarray(true_edges, jnp.int32) * (1 + lift_steps)
+    bill = jnp.asarray(true_edges, jnp.int32)
 
     def cond(state):
         _, _, _, done, rounds_, _ = state
@@ -447,8 +456,7 @@ def forest_cleanup_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
 
     def body(state):
         p, f, fi, _, rounds_, w = state
-        p, f, fi = hook_edges_forest_ids(p, f, fi, edges, edge_ids,
-                                         lift_steps=lift_steps)
+        p, f, fi = hook_edges_forest_ids(p, f, fi, edges, edge_ids)
         w = w.add(hook_ops=bill, hook_rounds=1)
         p, w = compress(p, w, bill_nodes=bill_nodes)
         return p, f, fi, edges_consistent(p, edges), rounds_ + 1, w
@@ -465,7 +473,6 @@ def forest_segment_scan_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                             parent_eidx: jnp.ndarray,
                             segments: jnp.ndarray, seg_ids: jnp.ndarray,
                             work: WorkCounters, true_counts: jnp.ndarray,
-                            lift_steps: int = 2,
                             bill_nodes: int | jnp.ndarray | None = None,
                             ) -> tuple[jnp.ndarray, jnp.ndarray,
                                        jnp.ndarray, WorkCounters]:
@@ -473,14 +480,11 @@ def forest_segment_scan_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     from-scratch forest rebuild over the surviving EdgeLog and by the
     scoped delete's scan phases, which pass ``bill_nodes`` so compress
     sweeps bill the affected region, not |V|)."""
-    bill = 1 + lift_steps
-
     def seg_body(carry, xs):
         p, f, fi, w = carry
         seg, ids, cnt = xs
-        p, f, fi = hook_edges_forest_ids(p, f, fi, seg, ids,
-                                         lift_steps=lift_steps)
-        w = w.add(hook_ops=cnt * bill, hook_rounds=1)
+        p, f, fi = hook_edges_forest_ids(p, f, fi, seg, ids)
+        w = w.add(hook_ops=cnt, hook_rounds=1)
         p, w = compress(p, w, bill_nodes=bill_nodes)
         return (p, f, fi, w), None
 
@@ -494,7 +498,6 @@ def forest_scan_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
                            parent_eidx: jnp.ndarray, packed: jnp.ndarray,
                            packed_ids: jnp.ndarray,
                            n_true: jnp.ndarray, work: WorkCounters, *,
-                           lift_steps: int = 2,
                            max_rounds: int = MAX_ROUNDS,
                            bill_nodes: int | jnp.ndarray | None = None,
                            ) -> tuple[jnp.ndarray, jnp.ndarray,
@@ -526,11 +529,10 @@ def forest_scan_rounds_ids(pi: jnp.ndarray, parents: jnp.ndarray,
     counts = jnp.clip(jnp.asarray(n_true, jnp.int32) - starts, 0, seg)
     pi, parents, parent_eidx, work = forest_segment_scan_ids(
         pi, parents, parent_eidx, segments, seg_ids, work, counts,
-        lift_steps=lift_steps, bill_nodes=bill_nodes)
+        bill_nodes=bill_nodes)
     return forest_cleanup_rounds_ids(
         pi, parents, parent_eidx, packed, packed_ids, work,
-        true_edges=n_true, lift_steps=lift_steps, max_rounds=max_rounds,
-        bill_nodes=bill_nodes)
+        true_edges=n_true, max_rounds=max_rounds, bill_nodes=bill_nodes)
 
 
 def pack_edge_rows(edges: jnp.ndarray, edge_ids: jnp.ndarray,
@@ -572,12 +574,8 @@ def forest_scoped_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
     untouched, so labels stay canonical (component minima) and the
     no-split case reproduces the pre-delete labels bit-identically.
 
-    Both phases run UNLIFTED hooks (``lift_steps=0``): a full compress
-    runs between every segment and every cleanup round, so each hook
-    reads already-flat labels and lifted re-gathers would be redundant
-    loads — billing them would triple the skeleton bill for work a
-    flat-label implementation never issues. Labels are bit-identical
-    either way (pinned by the conformance oracle scripts).
+    Both phases hook flat labels, like every composition here: a full
+    compress runs between every segment and every cleanup round.
     """
     n_v = pi.shape[0]
     bill_nodes = jnp.sum(vertex_mask).astype(jnp.int32)
@@ -589,7 +587,7 @@ def forest_scoped_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
                                             forest_keep)
     pi1, parents1, eidx1, work = forest_scan_rounds_ids(
         pi0, parents0, eidx0, skel, skel_ids, n_skel, work,
-        lift_steps=0, max_rounds=max_rounds, bill_nodes=bill_nodes)
+        max_rounds=max_rounds, bill_nodes=bill_nodes)
 
     crossing = jnp.logical_and(edge_mask,
                                pi1[edges[:, 0]] != pi1[edges[:, 1]])
@@ -603,7 +601,7 @@ def forest_scoped_rounds(pi: jnp.ndarray, parents: jnp.ndarray,
     n_cross = jnp.sum(crossing).astype(jnp.int32)
     pi2, parents2, eidx2, work = forest_cleanup_rounds_ids(
         pi1, parents1, eidx1, c_edges, c_ids, work, true_edges=n_cross,
-        lift_steps=0, max_rounds=max_rounds, bill_nodes=bill_nodes)
+        max_rounds=max_rounds, bill_nodes=bill_nodes)
     return pi2, parents2, eidx2, work
 
 
@@ -663,7 +661,7 @@ def segment_scan(pi: jnp.ndarray, segments: jnp.ndarray, ops: RoundOps,
         p, w = carry
         seg, cnt = xs
         p = ops.hook(p, seg)
-        w = w.add(hook_ops=cnt * ops.bill_lift, hook_rounds=1)
+        w = w.add(hook_ops=cnt, hook_rounds=1)
         p, w = ops.compress(p, w)
         return (p, w), None
 
@@ -690,7 +688,7 @@ def cleanup_rounds(pi: jnp.ndarray, edges: jnp.ndarray, ops: RoundOps,
     """
     if true_edges is None:
         true_edges = edges.shape[0]
-    bill = jnp.asarray(true_edges, jnp.int32) * ops.bill_lift
+    bill = jnp.asarray(true_edges, jnp.int32)
     true1 = jnp.asarray(true_edges, jnp.int32).reshape(1)
 
     def cond(state):
@@ -756,7 +754,6 @@ def scoped_rounds(pi: jnp.ndarray, edges: jnp.ndarray,
 def adaptive_rounds(edges: jnp.ndarray, num_nodes: int,
                     plan: SegmentationPlan, *,
                     ops: RoundOps | None = None,
-                    lift_steps: int = 2,
                     true_edges: int | jnp.ndarray | None = None,
                     max_rounds: int = MAX_ROUNDS,
                     ) -> tuple[jnp.ndarray, WorkCounters]:
@@ -767,7 +764,7 @@ def adaptive_rounds(edges: jnp.ndarray, num_nodes: int,
     Returns (labels, work) — callers add their own sync_rounds billing.
     """
     if ops is None:
-        ops = jnp_round_ops(lift_steps)
+        ops = jnp_round_ops()
     if true_edges is None:
         true_edges = plan.num_edges
     segments = pad_and_segment(edges, plan)

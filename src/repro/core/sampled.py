@@ -27,10 +27,13 @@ round machinery, in two jits (each a ``repro.analysis`` trace entry):
   routes the scan through the ``cc_fused`` Pallas kernel
   (``sampled_fused``; the kernel does not record forest edges).
 
-Work accounting: the sample phase bills ``valid-slot count x (1 +
-lift_steps)`` hook evaluations per round; the residue scan bills true
-residue edges only. On skewed inputs the total is a small fraction of
-what the full-scan backends pay — the headline of BENCH_sampled.
+Both phases hook flat labels (each hook follows a full compress, or
+starts from identity), so every hook gathers each endpoint's label once
+and issues no root chase. Work accounting: the sample phase bills the
+valid-slot count in hook evaluations per round; the residue scan bills
+true residue edges only, once per hook round. On skewed inputs the
+total is a small fraction of what the full-scan backends pay — the
+headline of BENCH_sampled.
 """
 from __future__ import annotations
 
@@ -58,10 +61,8 @@ class SampledResult(NamedTuple):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "k", "sample_rounds",
-                              "lift_steps"))
-def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds,
-                      lift_steps):
+    jax.jit, static_argnames=("num_nodes", "k", "sample_rounds"))
+def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds):
     """k-out sampling phase: CSR on device, hook each vertex to its
     first ``k`` neighbors for ``sample_rounds`` rounds, forest
     recorded. Returns ``(pi, parents, work, n_sampled, giant_label,
@@ -99,11 +100,9 @@ def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds,
         pi = jnp.arange(num_nodes, dtype=jnp.int32)
         parents = rounds.empty_forest(num_nodes)
         work = WorkCounters.zeros()
-        bill = n_sampled * (1 + lift_steps)
         for _ in range(sample_rounds):
-            pi, parents = rounds.hook_edges_forest(pi, parents, sampled,
-                                                   lift_steps=lift_steps)
-            work = work.add(hook_ops=bill, hook_rounds=1)
+            pi, parents = rounds.hook_edges_forest(pi, parents, sampled)
+            work = work.add(hook_ops=n_sampled, hook_rounds=1)
             pi, work = rounds.compress(pi, work)
 
     with jax.named_scope("sampled.census"):
@@ -114,11 +113,10 @@ def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "num_segments", "lift_steps",
-                              "fused", "interpret"))
+    jax.jit, static_argnames=("num_nodes", "num_segments", "fused",
+                              "interpret"))
 def _residue_scan_jit(edges, true_edges, pi, parents, work, *,
-                      num_nodes, num_segments, lift_steps, fused,
-                      interpret):
+                      num_nodes, num_segments, fused, interpret):
     """Adaptive Fig. 4 scan over the residue only: edges whose
     endpoints the sampling phase left in different components, packed
     to a prefix and billed as a traced count. Starts from the sampled
@@ -135,17 +133,16 @@ def _residue_scan_jit(edges, true_edges, pi, parents, work, *,
     segments = rounds.pad_and_segment(packed, plan)
     counts = rounds.segment_true_counts(n_res, plan)
     if fused:
-        ops = rounds.fused_round_ops(lift_steps, interpret=interpret)
+        ops = rounds.fused_round_ops(interpret=interpret)
         pi, work = rounds.segment_scan(pi, segments, ops, work,
                                        true_counts=counts)
         pi, work = rounds.cleanup_rounds(pi, segments.reshape(-1, 2),
                                          ops, work, true_edges=n_res)
     else:
         pi, parents, work = rounds.forest_segment_scan(
-            pi, parents, segments, work, counts, lift_steps=lift_steps)
+            pi, parents, segments, work, counts)
         pi, parents, work = rounds.forest_cleanup_rounds(
-            pi, parents, segments.reshape(-1, 2), work,
-            true_edges=n_res, lift_steps=lift_steps)
+            pi, parents, segments.reshape(-1, 2), work, true_edges=n_res)
     return pi, parents, work, n_res
 
 
@@ -153,7 +150,6 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
                   k: int = SAMPLE_K,
                   sample_rounds: int = SAMPLE_ROUNDS,
                   num_segments: int | None = None,
-                  lift_steps: int = 2,
                   fused: bool = False,
                   interpret: bool | None = None) -> SampledResult:
     """The sampled engine entry (the ``sampled`` / ``sampled_fused``
@@ -195,12 +191,11 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
     with obs.span("sampled.sample_phase", num_nodes=v, k=k):
         pi, parents, s_work, n_sampled, giant, giant_size = \
             _sample_phase_jit(g.edges, true, num_nodes=v, k=k,
-                              sample_rounds=sample_rounds,
-                              lift_steps=lift_steps)
+                              sample_rounds=sample_rounds)
     with obs.span("sampled.residue_scan", num_nodes=v):
         pi, parents, work, n_res = _residue_scan_jit(
             g.edges, true, pi, parents, s_work, num_nodes=v,
-            num_segments=g.plan.num_segments, lift_steps=lift_steps,
+            num_segments=g.plan.num_segments,
             fused=fused, interpret=bool(interpret))
     work = work.add(sync_rounds=2)      # one jit call per phase
     stats = {"sample_hook_ops": s_work.hook_ops,

@@ -136,7 +136,8 @@ class FleetService:
     queue; ``step()`` runs one pipelined fleet tick; ``run()`` drains
     everything including the pipeline tail. One object, any mesh size —
     on a single device it degrades to exactly one shard (the engine's
-    batching still applies)."""
+    batching still applies). ``lift_steps`` is accepted and has no
+    effect: every engine hooks flat labels."""
 
     def __init__(self, devices=None, *, slots_per_device: int = 32,
                  lift_steps: int = 2,
@@ -163,8 +164,7 @@ class FleetService:
                                  "different mesh")
             self.runners = runners
         else:
-            self.runners = DistributedRunnerCache(self.mesh, ("data",),
-                                                  lift_steps=lift_steps)
+            self.runners = DistributedRunnerCache(self.mesh, ("data",))
         self.shard_threshold = int(shard_threshold)
         self.rebalance_every = int(rebalance_every)
         self.rebalance_factor = float(rebalance_factor)

@@ -73,11 +73,12 @@ def test_bucketize_groups_by_padded_shape():
 
 
 def test_batched_work_bills_true_edges_only():
-    """hook_ops must be a multiple of E_true * (1 + lift_steps) even
-    though the bucket pads the edge list (padding is free)."""
+    """hook_ops must be a multiple of E_true (one hook evaluation per
+    true edge per round; ``lift_steps`` issues no chase) even though
+    the bucket pads the edge list (padding is free)."""
     g = G.chain(17)           # 16 edges -> padded to 32 in its bucket
     res = connected_components_batched([g], lift_steps=2)[0]
-    bill = g.num_edges * 3
+    bill = g.num_edges
     assert int(res.work.hook_ops) % bill == 0
     assert int(res.work.hook_ops) >= bill
     # jump_ops bill the true |V| per sweep, not the padded bucket height
@@ -383,8 +384,9 @@ def test_segment_true_counts_sum_to_true_edges():
 
 def test_adaptive_hook_ops_bill_true_edges():
     """Single-graph adaptive billing: with padding present, hook_ops is
-    (1 + cleanup_rounds) * E_true * (1 + lift_steps) — never a function
-    of the padded segment size."""
+    (1 + cleanup_rounds) * E_true — never a function of the padded
+    segment size, nor of ``lift_steps`` (every hook reads flat labels
+    and issues no chase)."""
     g = G.chain(17)                          # 16 edges
     lift, segs = 2, 3                        # seg=6 -> 18 padded slots
     plan = plan_segmentation(g.num_edges, g.num_nodes, segs)
@@ -395,8 +397,7 @@ def test_adaptive_hook_ops_bill_true_edges():
     assert cleanup >= 0
     # the old (buggy) padded billing would have charged
     # plan.segment_size per scan segment instead of the true count
-    assert int(res.work.hook_ops) == \
-        (1 + cleanup) * g.num_edges * (1 + lift)
+    assert int(res.work.hook_ops) == (1 + cleanup) * g.num_edges
 
 
 def test_hostloop_unknown_method_raises():

@@ -297,7 +297,7 @@ def test_fused_single_launch_vs_per_round_backend():
     old_jaxpr = jax.make_jaxpr(
         lambda e: cc_mod._cc_adaptive_pallas(
             e, num_nodes=g.num_nodes, num_segments=plan.num_segments,
-            lift_steps=2, interpret=True))(dg.edges).jaxpr
+            interpret=True))(dg.edges).jaxpr
     assert _launch_lower_bound(old_jaxpr) >= plan.num_segments + 1
 
     # scan-only sweep count from the fused kernel's counters (verified

@@ -469,17 +469,15 @@ def _lowered_text(program: str) -> str:
     edges, true = jnp.zeros((64, 2), jnp.int32), jnp.int32(64)
     if program == "cc_adaptive":
         lowered = _cc_jit.lower(edges, None, num_nodes=32,
-                                method="adaptive", num_segments=4,
-                                lift_steps=2)
+                                method="adaptive", num_segments=4)
     elif program == "sample_phase":
         lowered = _sample_phase_jit.lower(edges, true, num_nodes=32, k=2,
-                                          sample_rounds=2, lift_steps=2)
+                                          sample_rounds=2)
     else:
         lowered = _residue_scan_jit.lower(
             edges, true, jnp.arange(32, dtype=jnp.int32),
             jnp.full((32, 2), -1, jnp.int32), WorkCounters.zeros(),
-            num_nodes=32, num_segments=4, lift_steps=2, fused=False,
-            interpret=True)
+            num_nodes=32, num_segments=4, fused=False, interpret=True)
     return lowered.as_text(debug_info=True)
 
 
