@@ -257,28 +257,28 @@ def _static_specs():
 def _sampled_specs():
     import jax
 
-    from repro.core import rounds, sampled as sampled_mod
+    from repro.core import sampled as sampled_mod
     from repro.core.segmentation import adaptive_num_segments
 
     def build_sample(v, e):
         def fn(edges, true_edges):
             return sampled_mod._sample_phase_jit(
                 edges, true_edges, num_nodes=v, k=sampled_mod.SAMPLE_K,
-                sample_rounds=sampled_mod.SAMPLE_ROUNDS)
+                sample_rounds=sampled_mod.SAMPLE_ROUNDS, forest=False)
         return (fn, (jax.ShapeDtypeStruct((e, 2), jnp.int32),
                      jax.ShapeDtypeStruct((), jnp.int32)),
                 [VarInfo(range=(0, v - 1), padded=True),
                  VarInfo(range=(0, e), mask=True)])
 
     def residue_build(v, e, fused):
-        # parents/work start fresh inside the trace (scalar constants
-        # only); pi arrives as the sampling phase's label array
+        # the labels-only programs the backends run: work starts fresh
+        # inside the trace (scalar constants only); pi arrives as the
+        # sampling phase's label array
         def fn(edges, true_edges, pi):
             return sampled_mod._residue_scan_jit(
-                edges, true_edges, pi, rounds.empty_forest(v),
-                WorkCounters.zeros(), num_nodes=v,
-                num_segments=adaptive_num_segments(e, v),
-                fused=fused, interpret=True)
+                edges, true_edges, pi, None, WorkCounters.zeros(),
+                num_nodes=v, num_segments=adaptive_num_segments(e, v),
+                fused=fused, interpret=True, forest=False)
         return (fn, (jax.ShapeDtypeStruct((e, 2), jnp.int32),
                      jax.ShapeDtypeStruct((), jnp.int32),
                      jax.ShapeDtypeStruct((v,), jnp.int32)),

@@ -377,7 +377,7 @@ def solve_forest(
                             WorkCounters.zeros())
     if method == "sampled":
         from repro.core.sampled import solve_sampled
-        res = solve_sampled(g, num_segments=num_segments, fused=False)
+        res = solve_sampled(g, num_segments=num_segments, forest=True)
         return ForestResult(res.labels, res.parents, res.work)
     t = g.true_edges_static
     true = None if (t is not None and t == int(g.edges.shape[0])) \

@@ -12,10 +12,10 @@ round machinery, in two jits (each a ``repro.analysis`` trace entry):
   undirected storage still samples both endpoints), take the first
   ``k`` slots per vertex (k-out sampling; invalid slots become (0, 0)
   no-ops and are never billed), then run ``sample_rounds`` fixed
-  hook+compress rounds over the |V|*k sampled edges — recording the
-  spanning-forest parent edges as it hooks. The giant component is
-  identified with the existing census kernel (one scatter-add +
-  argmax) for telemetry; correctness never depends on it.
+  hook+compress rounds over the |V|*k sampled edges. The giant
+  component is identified with the existing census kernel (one
+  scatter-add + argmax) for telemetry; correctness never depends on
+  it.
 * ``_residue_scan_jit`` — the residue is every stored edge whose
   endpoints still carry different labels (a strict superset filter of
   "both endpoints outside the giant component": intra-component edges
@@ -25,7 +25,19 @@ round machinery, in two jits (each a ``repro.analysis`` trace entry):
   Fig. 4 pipeline from the sampled labels: segment scan + trailing
   cleanup, billing the traced residue count only. ``fused=True``
   routes the scan through the ``cc_fused`` Pallas kernel
-  (``sampled_fused``; the kernel does not record forest edges).
+  (``sampled_fused``).
+
+Both jits take a static ``forest``. Only ``cc.solve_forest`` (behind
+``Solver.spanning_forest()``) asks for one: then every hook records
+the spanning-forest parent edge it merged through
+(``rounds.hook_edges_forest`` and the forest compositions), into an
+int32 [V, 2] table carried through both phases. A labels-only solve
+(``solve()``, the ``sampled`` / ``sampled_fused`` backends) hooks with
+the plain compositions and allocates no table: the forest hooks make
+the same ``pi`` updates and bill the same, so labels and
+``WorkCounters`` are bit-identical either way, and the labels-only
+hook issues 2 gathers and one scatter-min per edge instead of 5
+gathers, two scatter-mins and the parent-row scatter.
 
 Both phases hook flat labels (each hook follows a full compress, or
 starts from identity), so every hook gathers each endpoint's label once
@@ -52,21 +64,24 @@ SAMPLE_ROUNDS = 2     # fixed hook+compress rounds over the sample
 
 
 class SampledResult(NamedTuple):
-    """labels + forest + work, plus the phase-split telemetry."""
+    """labels + forest (None unless asked for) + work, plus the
+    phase-split telemetry."""
 
     labels: jnp.ndarray           # int32 [V] canonical min-id labels
-    parents: jnp.ndarray          # int32 [V, 2] forest edges (-1 = root)
+    parents: jnp.ndarray | None   # int32 [V, 2] forest edges, -1 = root
     work: WorkCounters            # combined (sample + residue) billing
     stats: dict                   # device scalars: phase split + giant
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_nodes", "k", "sample_rounds"))
-def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds):
+    jax.jit, static_argnames=("num_nodes", "k", "sample_rounds", "forest"))
+def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds,
+                      forest):
     """k-out sampling phase: CSR on device, hook each vertex to its
-    first ``k`` neighbors for ``sample_rounds`` rounds, forest
-    recorded. Returns ``(pi, parents, work, n_sampled, giant_label,
-    giant_size)`` — all device values."""
+    first ``k`` neighbors for ``sample_rounds`` rounds, the forest
+    recorded iff ``forest``. Returns ``(pi, parents, work, n_sampled,
+    giant_label, giant_size)`` — all device values, ``parents`` None
+    without a forest."""
     e = edges.shape[0]
     with jax.named_scope("sampled.csr"):
         # symmetrize so vertices stored only as targets still get
@@ -98,10 +113,14 @@ def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds):
         n_sampled = jnp.sum(valid).astype(jnp.int32)
 
         pi = jnp.arange(num_nodes, dtype=jnp.int32)
-        parents = rounds.empty_forest(num_nodes)
+        parents = rounds.empty_forest(num_nodes) if forest else None
         work = WorkCounters.zeros()
         for _ in range(sample_rounds):
-            pi, parents = rounds.hook_edges_forest(pi, parents, sampled)
+            if forest:
+                pi, parents = rounds.hook_edges_forest(pi, parents,
+                                                       sampled)
+            else:
+                pi = rounds.hook_edges(pi, sampled)
             work = work.add(hook_ops=n_sampled, hook_rounds=1)
             pi, work = rounds.compress(pi, work)
 
@@ -114,13 +133,15 @@ def _sample_phase_jit(edges, true_edges, *, num_nodes, k, sample_rounds):
 
 @functools.partial(
     jax.jit, static_argnames=("num_nodes", "num_segments", "fused",
-                              "interpret"))
+                              "interpret", "forest"))
 def _residue_scan_jit(edges, true_edges, pi, parents, work, *,
-                      num_nodes, num_segments, fused, interpret):
+                      num_nodes, num_segments, fused, interpret, forest):
     """Adaptive Fig. 4 scan over the residue only: edges whose
     endpoints the sampling phase left in different components, packed
     to a prefix and billed as a traced count. Starts from the sampled
-    labels (NOT identity). Returns ``(pi, parents, work, n_residue)``."""
+    labels (NOT identity), and with ``forest`` from the sampled
+    ``parents`` (None otherwise; ``fused`` records none). Returns
+    ``(pi, parents, work, n_residue)``."""
     e = edges.shape[0]
     with jax.named_scope("sampled.pack"):
         row_real = jnp.arange(e, dtype=jnp.int32) < true_edges
@@ -132,17 +153,18 @@ def _residue_scan_jit(edges, true_edges, pi, parents, work, *,
     plan = plan_segmentation(e, num_nodes, num_segments)
     segments = rounds.pad_and_segment(packed, plan)
     counts = rounds.segment_true_counts(n_res, plan)
-    if fused:
-        ops = rounds.fused_round_ops(interpret=interpret)
-        pi, work = rounds.segment_scan(pi, segments, ops, work,
-                                       true_counts=counts)
-        pi, work = rounds.cleanup_rounds(pi, segments.reshape(-1, 2),
-                                         ops, work, true_edges=n_res)
-    else:
+    if forest:
         pi, parents, work = rounds.forest_segment_scan(
             pi, parents, segments, work, counts)
         pi, parents, work = rounds.forest_cleanup_rounds(
             pi, parents, segments.reshape(-1, 2), work, true_edges=n_res)
+    else:
+        ops = rounds.fused_round_ops(interpret=interpret) if fused \
+            else rounds.jnp_round_ops()
+        pi, work = rounds.segment_scan(pi, segments, ops, work,
+                                       true_counts=counts)
+        pi, work = rounds.cleanup_rounds(pi, segments.reshape(-1, 2),
+                                         ops, work, true_edges=n_res)
     return pi, parents, work, n_res
 
 
@@ -151,26 +173,33 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
                   sample_rounds: int = SAMPLE_ROUNDS,
                   num_segments: int | None = None,
                   fused: bool = False,
-                  interpret: bool | None = None) -> SampledResult:
+                  interpret: bool | None = None,
+                  forest: bool = False) -> SampledResult:
     """The sampled engine entry (the ``sampled`` / ``sampled_fused``
     backends dispatch here; go through the ``repro.api`` facade).
 
     Two device programs: the k-out sampling phase, then the adaptive
     scan over the residue. ``fused=True`` runs the residue scan
-    through the ``cc_fused`` Pallas kernel (no forest recording on the
-    residue — ``sampled_fused`` reports ``spanning_forest=False``).
+    through the ``cc_fused`` Pallas kernel. ``forest=True`` (from
+    ``cc.solve_forest`` alone) records the spanning forest in
+    ``SampledResult.parents``; it is None otherwise, and labels and
+    work are the same either way. The fused kernel records no forest,
+    so the two do not combine (``sampled_fused`` reports
+    ``spanning_forest=False``).
     Each phase runs under its own ``repro.obs`` span, and the
     sampled-vs-residue work split lands in ``SampledResult.stats``
     (device scalars; nothing here syncs).
     """
     from repro.graphs.device import as_device_graph
     from repro.obs import trace as obs
+    if fused and forest:
+        raise ValueError("the fused residue scan records no forest")
     g = as_device_graph(graph, num_nodes, num_segments=num_segments)
     v = g.num_nodes
     if v <= 0:
         z = jnp.zeros((), jnp.int32)
         return SampledResult(jnp.zeros((0,), jnp.int32),
-                             rounds.empty_forest(0),
+                             rounds.empty_forest(0) if forest else None,
                              WorkCounters.zeros(),
                              {"sample_hook_ops": z, "residue_hook_ops": z,
                               "n_sampled": z, "n_residue": z,
@@ -178,7 +207,7 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
     if g.edges.shape[0] == 0 or g.true_edges_static == 0:
         z = jnp.zeros((), jnp.int32)
         return SampledResult(jnp.arange(v, dtype=jnp.int32),
-                             rounds.empty_forest(v),
+                             rounds.empty_forest(v) if forest else None,
                              WorkCounters.zeros(),
                              {"sample_hook_ops": z, "residue_hook_ops": z,
                               "n_sampled": z, "n_residue": z,
@@ -191,12 +220,12 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
     with obs.span("sampled.sample_phase", num_nodes=v, k=k):
         pi, parents, s_work, n_sampled, giant, giant_size = \
             _sample_phase_jit(g.edges, true, num_nodes=v, k=k,
-                              sample_rounds=sample_rounds)
+                              sample_rounds=sample_rounds, forest=forest)
     with obs.span("sampled.residue_scan", num_nodes=v):
         pi, parents, work, n_res = _residue_scan_jit(
             g.edges, true, pi, parents, s_work, num_nodes=v,
             num_segments=g.plan.num_segments,
-            fused=fused, interpret=bool(interpret))
+            fused=fused, interpret=bool(interpret), forest=forest)
     work = work.add(sync_rounds=2)      # one jit call per phase
     stats = {"sample_hook_ops": s_work.hook_ops,
              "residue_hook_ops": work.hook_ops - s_work.hook_ops,
@@ -207,4 +236,7 @@ def solve_sampled(graph, num_nodes: int | None = None, *,
     obs.count_device("sampled.solves", 1)
     obs.count_device("sampled.hook_ops.sample", stats["sample_hook_ops"])
     obs.count_device("sampled.hook_ops.residue", stats["residue_hook_ops"])
+    if forest:
+        # a host counter, always on: how many solves paid for a forest
+        obs.count("sampled.forest_solves")
     return SampledResult(pi, parents, work, stats)

@@ -14,7 +14,8 @@ never move a label. Two guards:
   issued, so it is no longer billed);
 * **structure** — the lowered programs issue 2 gathers per plain hook
   and 5 per forest hook under the ``hook`` named scope, so a chase
-  cannot come back unnoticed.
+  cannot come back unnoticed; the labels-only sampled programs hook
+  plainly and scatter into no parent table.
 """
 import contextlib
 
@@ -58,10 +59,11 @@ def _chasing():
     Yields a list that grows by one per traced chasing hook."""
     calls = []
 
+    def hook(pi, edges, lift_steps=0):
+        hi, lo = _chase(pi, edges, calls)
+        return pi.at[hi].min(lo)
+
     def ops(bill_nodes=None, **_):
-        def hook(pi, edges):
-            hi, lo = _chase(pi, edges, calls)
-            return pi.at[hi].min(lo)
         return rounds.RoundOps(
             hook=hook,
             compress=lambda p, w: rounds.compress(p, w,
@@ -77,6 +79,7 @@ def _chasing():
                 parent_eidx.at[at].set(edge_ids, mode="drop"))
 
     mp = pytest.MonkeyPatch()
+    mp.setattr(rounds, "hook_edges", hook)
     mp.setattr(rounds, "jnp_round_ops", ops)
     mp.setattr(rounds, "fused_round_ops", ops)
     mp.setattr(rounds, "hook_edges_forest", forest_hook)
@@ -114,6 +117,12 @@ def _run_forest(n, e):
 
 
 def _run_sampled(n, e):
+    from repro.core.cc import solve_forest
+    r = solve_forest(e, n, method="sampled")
+    return _out(r.labels, r.work, r.parents)
+
+
+def _run_sampled_labels(n, e):
     from repro.core.sampled import solve_sampled
     r = solve_sampled(e, n)
     return _out(r.labels, r.work, r.parents)
@@ -149,7 +158,8 @@ def _run_dynamic(n, e):
 
 
 PATHS = {"adaptive": _run_adaptive, "solve_forest": _run_forest,
-         "sampled": _run_sampled, "pallas_fused": _run_fused,
+         "sampled": _run_sampled, "sampled_labels": _run_sampled_labels,
+         "pallas_fused": _run_fused,
          "incremental": _run_incremental, "dynamic_delete": _run_dynamic}
 
 
@@ -186,20 +196,33 @@ def test_flat_hook_matches_chasing_reference(path):
 # Structure: gathers under the hook scope of the lowered programs
 # ---------------------------------------------------------------------------
 
-def _hook_gathers(jaxpr) -> int:
+V = 32              # vertices of the traced programs
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
-    n = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather" and \
-                "hook" in str(eqn.source_info.name_stack).split("/"):
-            n += 1
+        yield eqn
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 if isinstance(sub, ClosedJaxpr):
-                    n += _hook_gathers(sub.jaxpr)
+                    yield from _eqns(sub.jaxpr)
                 elif isinstance(sub, Jaxpr):
-                    n += _hook_gathers(sub)
-    return n
+                    yield from _eqns(sub)
+
+
+def _hook_gathers(jaxpr) -> int:
+    return sum(eqn.primitive.name == "gather" and
+               "hook" in str(eqn.source_info.name_stack).split("/")
+               for eqn in _eqns(jaxpr))
+
+
+def _parent_scatters(jaxpr) -> int:
+    """Scatters whose operand is a [V, 2] table: the forest's rows."""
+    return sum(eqn.primitive.name.startswith("scatter") and
+               eqn.invars[0].aval.shape == (V, 2)
+               for eqn in _eqns(jaxpr))
 
 
 def _traced(program: str):
@@ -208,24 +231,30 @@ def _traced(program: str):
     from repro.core.sampled import _residue_scan_jit, _sample_phase_jit
     edges, true = jnp.zeros((64, 2), jnp.int32), jnp.int32(64)
     if program == "cc_adaptive":
-        return _cc_jit.trace(edges, None, num_nodes=32, method="adaptive",
+        return _cc_jit.trace(edges, None, num_nodes=V, method="adaptive",
                              num_segments=4)
-    if program == "sample_phase":
-        return _sample_phase_jit.trace(edges, true, num_nodes=32, k=2,
-                                       sample_rounds=2)
+    forest = not program.endswith("_labels")
+    if program.startswith("sample_phase"):
+        return _sample_phase_jit.trace(edges, true, num_nodes=V, k=2,
+                                       sample_rounds=2, forest=forest)
     return _residue_scan_jit.trace(
-        edges, true, jnp.arange(32, dtype=jnp.int32),
-        jnp.full((32, 2), -1, jnp.int32), WorkCounters.zeros(),
-        num_nodes=32, num_segments=4, fused=False, interpret=True)
+        edges, true, jnp.arange(V, dtype=jnp.int32),
+        jnp.full((V, 2), -1, jnp.int32) if forest else None,
+        WorkCounters.zeros(), num_nodes=V, num_segments=4, fused=False,
+        interpret=True, forest=forest)
 
 
 @pytest.mark.parametrize("program,hooks,per_hook", [
     ("cc_adaptive", 2, 2),      # segment scan + cleanup, plain hooks
     ("sample_phase", 2, 5),     # two unrolled forest hook rounds
-    ("residue_scan", 2, 5)])    # segment scan + cleanup, forest hooks
+    ("residue_scan", 2, 5),     # segment scan + cleanup, forest hooks
+    ("sample_phase_labels", 2, 2),    # the labels-only programs that
+    ("residue_scan_labels", 2, 2)])   # solve() runs: plain hooks
 def test_hooks_gather_each_endpoint_once(program, hooks, per_hook):
     """A plain hook gathers ``pi[u]`` and ``pi[v]``; a forest hook adds
     ``new_pi[hi]``, ``pi[hi]`` and ``winner[hi]``. A root chase would
-    add two gathers per step."""
+    add two gathers per step. Only a forest hook writes rows of the
+    [V, 2] parent table."""
     jaxpr = _traced(program).jaxpr.jaxpr
     assert _hook_gathers(jaxpr) == hooks * per_hook
+    assert (_parent_scatters(jaxpr) > 0) == (per_hook == 5)

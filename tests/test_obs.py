@@ -467,17 +467,20 @@ def _lowered_text(program: str) -> str:
     from repro.core.rounds import WorkCounters
     from repro.core.sampled import _residue_scan_jit, _sample_phase_jit
     edges, true = jnp.zeros((64, 2), jnp.int32), jnp.int32(64)
+    # ``*_labels``: the labels-only programs solve() runs
+    forest = not program.endswith("_labels")
     if program == "cc_adaptive":
         lowered = _cc_jit.lower(edges, None, num_nodes=32,
                                 method="adaptive", num_segments=4)
-    elif program == "sample_phase":
+    elif program.startswith("sample_phase"):
         lowered = _sample_phase_jit.lower(edges, true, num_nodes=32, k=2,
-                                          sample_rounds=2)
+                                          sample_rounds=2, forest=forest)
     else:
         lowered = _residue_scan_jit.lower(
             edges, true, jnp.arange(32, dtype=jnp.int32),
-            jnp.full((32, 2), -1, jnp.int32), WorkCounters.zeros(),
-            num_nodes=32, num_segments=4, fused=False, interpret=True)
+            jnp.full((32, 2), -1, jnp.int32) if forest else None,
+            WorkCounters.zeros(), num_nodes=32, num_segments=4,
+            fused=False, interpret=True, forest=forest)
     return lowered.as_text(debug_info=True)
 
 
@@ -486,7 +489,11 @@ def _lowered_text(program: str) -> str:
     ("sample_phase", {"sampled.csr", "sampled.sample", "sampled.census",
                       "hook", "compress"}),
     ("residue_scan", {"sampled.pack", "segment_scan", "cleanup", "hook",
-                      "compress"})])
+                      "compress"}),
+    ("sample_phase_labels", {"sampled.csr", "sampled.sample",
+                             "sampled.census", "hook", "compress"}),
+    ("residue_scan_labels", {"sampled.pack", "segment_scan", "cleanup",
+                             "hook", "compress"})])
 def test_round_phases_carry_named_scopes_in_hlo(program, scopes):
     """The phases' named scopes reach the op names of the
     lowered programs, where the profiler's ``tf_op`` paths come from."""

@@ -16,14 +16,18 @@ forest product. This module pins what is SPECIFIC to sampling:
   ``jnp`` adaptive backend's on a power-law stand-in, labels identical;
 * the ``repro.obs`` work split (``sampled.hook_ops.sample`` /
   ``.residue`` device counters, kept while tracing is on) and the
-  per-plan ``sampled_stats`` artifact.
+  per-plan ``sampled_stats`` artifact;
+* labels-only solves — a forest is recorded (and
+  ``sampled.forest_solves`` counted) only for ``spanning_forest()``,
+  and leaving it out changes no label, counter or statistic.
 """
 import numpy as np
 import pytest
 
-from _graphgen import power_law
+from _graphgen import corpus, power_law
 from repro.api import Solver, solve
 from repro.connectivity import policy
+from repro.core.rounds import WorkCounters
 from repro.core.unionfind import connected_components_oracle
 
 
@@ -182,7 +186,6 @@ def test_sampled_obs_counters_record_work_split():
 def test_sampled_fused_matches_sampled_labels_and_counters():
     """The fused-residue variant is label-identical; its counters match
     the jnp-residue variant's (both bill true work only)."""
-    from repro.core.rounds import WorkCounters
     n, e = 512, 4096
     edges = _powerlaw_edges(n, e, seed=11)
     a = solve(edges, n, backend="sampled")
@@ -191,3 +194,55 @@ def test_sampled_fused_matches_sampled_labels_and_counters():
                                   np.asarray(b.labels))
     for field, x, y in zip(WorkCounters._fields, a.work, b.work):
         assert int(x) == int(y), (field, int(x), int(y))
+
+
+# ---------------------------------------------------------------------------
+# Labels-only solves: a forest is recorded only when the caller asks
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,edges", [
+    pytest.param(n, e, id=name) for name, n, e in corpus()])
+def test_labels_only_solve_matches_forest_solve(n, edges):
+    """``solve_sampled`` without a forest gives the labels, counters and
+    phase split of the forest-recording solve bit for bit, the labels
+    of the ``sampled`` backend, and no parent table."""
+    from repro.core.sampled import solve_sampled
+    plain = solve_sampled(edges, n)
+    forest = solve_sampled(edges, n, forest=True)
+    assert plain.parents is None
+    assert np.asarray(forest.parents).shape == (n, 2)
+    np.testing.assert_array_equal(np.asarray(plain.labels),
+                                  np.asarray(forest.labels))
+    for field, x, y in zip(WorkCounters._fields, plain.work, forest.work):
+        assert int(x) == int(y), (field, int(x), int(y))
+    assert plain.stats.keys() == forest.stats.keys()
+    for key in plain.stats:
+        assert int(plain.stats[key]) == int(forest.stats[key]), key
+    np.testing.assert_array_equal(
+        np.asarray(plain.labels),
+        np.asarray(solve(edges, n, backend="sampled").labels))
+
+
+def test_forest_solves_counter_counts_only_forest_requests():
+    """``solve("auto")`` routed to ``sampled`` records no forest; the
+    ``spanning_forest()`` that follows records one."""
+    from repro.obs import trace as obs
+    obs.tracer().reset()
+    try:
+        s = Solver.open(_powerlaw_edges(1024, 8192), 1024,
+                        policy_cache=policy.AutotuneCache())
+        s.solve("auto")
+        assert s.last_method == "sampled"
+        assert obs.tracer().counters.get("sampled.forest_solves", 0) == 0
+        forest = s.spanning_forest()
+        assert obs.tracer().counters["sampled.forest_solves"] == 1
+    finally:
+        obs.tracer().reset()
+    assert np.asarray(forest.parents).shape == (1024, 2)
+
+
+def test_fused_residue_records_no_forest():
+    from repro.core.sampled import solve_sampled
+    with pytest.raises(ValueError, match="no forest"):
+        solve_sampled(_powerlaw_edges(64, 256), 64, fused=True,
+                      forest=True)

@@ -121,3 +121,43 @@ def test_cc_kernel_lowers_for_v5e(one_chip, build, refusal):
         if re.search(refusal, str(e)):
             raise MosaicRefusal(str(e).splitlines()[0]) from e
         raise
+
+
+# the soc-live-journal cell's graph: |V|, edge rows, residue segments
+SOC = (1 << 22, 59_696_054, 28)
+
+
+def _while_loops(hlo: str) -> dict:
+    """``{op path: tuple type}`` of every ``while`` in compiled HLO."""
+    return {m.group(2): m.group(1) for m in re.finditer(
+        r'^\s*%\S+ = \((.*)\) while\(.*?op_name="[^"/]*/([^"]*)"', hlo,
+        re.M)}
+
+
+def test_labels_only_residue_scan_keeps_pi_in_vmem_at_soc_size(one_chip):
+    """At the soc cell's size XLA keeps the 16 MiB ``pi`` in on-chip
+    memory (``S(1)``) across the residue's segment scan, where every
+    compress sweep gathers it: the placement that took the scan's
+    compress from 3.3x slower back to the parent's speed. It is the
+    compiler's heuristic, not the program's, so any change to the
+    program has to keep it. The cleanup loop's compress holds pi in
+    HBM; soc's cleanup runs no hook round."""
+    from repro.core.rounds import WorkCounters
+    from repro.core.sampled import _residue_scan_jit
+    v, e, segments = SOC
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    work = WorkCounters(*(s(()) for _ in WorkCounters._fields))
+    hlo = _residue_scan_jit.lower(
+        s((e, 2)), s(()), s((v,)), None, work, num_nodes=v,
+        num_segments=segments, fused=False, interpret=True,
+        forest=False).compile().as_text()
+    loops = _while_loops(hlo)
+    scan = [loops["segment_scan/while"]] + [
+        t for path, t in loops.items()
+        if path.startswith("segment_scan/while/")
+        and path.endswith("compress/while")]
+    assert len(scan) == 2, sorted(loops)
+    for carried in scan:
+        assert re.search(rf"s32\[{v}\]{{[^}}]*S\(1\)", carried), carried
